@@ -189,9 +189,11 @@ def auction_scaling_sharded():
     100k×1000 sparse market settled by sharded_clock_auction on 8 virtual
     CPU devices (subprocess, --xla_force_host_platform_device_count=8; the
     same program runs on real multi-host meshes).  Wall time is apples-to-
-    apples with auction_scaling's round-capped largest case.
-    derived: clock rounds/s on the 8-way sharded path."""
-    env = dict(os.environ)
+    apples with auction_scaling's round-capped largest case.  The child is
+    held to the CPU: this process may already own an accelerator, and a
+    chip serves one process at a time.
+    derived: clock rounds/s on the 8-way sharded path (virtual CPU devices)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     env.setdefault("PYTHONPATH", "src")
     out = subprocess.run(
@@ -206,7 +208,8 @@ def auction_scaling_sharded():
     _, ndev, u, r, dt, rounds, conv = line.split()
     dt, rounds = float(dt), int(rounds)
     print(
-        f"#   sharded {u}x{r} on {ndev} devices: {dt*1e3:.1f} ms, {rounds} rounds "
+        f"#   sharded {u}x{r} on {ndev} virtual CPU devices: {dt*1e3:.1f} ms, "
+        f"{rounds} rounds "
         f"({rounds/dt:.0f}/s), converged={conv}",
         file=sys.stderr,
     )
@@ -1171,6 +1174,9 @@ def _host_tag() -> str:
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.configure()
     args = sys.argv[1:]
     write_json = "--json" in args
     want = [a for a in args if not a.startswith("--")] or list(BENCHES)

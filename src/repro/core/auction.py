@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..sharding import shard_map
 from .types import (
     AuctionProblem,
     AuctionResult,
@@ -933,7 +932,7 @@ def _sharded_clock_impl(
         )
 
     ax = axis_name
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(), P()),

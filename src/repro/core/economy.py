@@ -422,7 +422,7 @@ class Economy:
         reliability_discount: float = 1.0,
         fused: bool = False,
         pipeline: bool = False,
-        fused_backend: str | None = None,
+        fused_backend: str | None = "jnp",
         fused_slack: bool = False,
     ):
         self.clusters = list(clusters)
@@ -1727,7 +1727,7 @@ class Economy:
     def _fused_const(self) -> tuple:
         if self._device_const is None or self._state_dirty:
             pop = self.pop
-            with jax.experimental.enable_x64(True):
+            with jax.enable_x64(True):
                 self._device_const = tuple(
                     jnp.asarray(self._pad_agents(np.asarray(a), 0))
                     for a in (
@@ -1853,7 +1853,7 @@ class Economy:
         """Upload epoch inputs and launch the fused program (async)."""
         fn = self._fused_program()
         n = len(self.pop)
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             if dry_run:
                 # ephemeral state copies: donation consumes them, the
                 # persistent device state and host mirrors are untouched

@@ -149,7 +149,7 @@ class DeviceMarketState:
             placed = np.concatenate([placed, pad_i])
             home = np.concatenate([home, pad_i])
             fill = np.concatenate([fill, np.ones(cap - n, fill.dtype)])
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             return cls(
                 placed=jnp.asarray(placed),
                 home=jnp.asarray(home),
@@ -168,7 +168,7 @@ def build_fused_epoch(
     clock_retries: int = 0,
     ration_fallback: bool = False,
     settle_blocks: int = 8,
-    backend: str | None = None,
+    backend: str | None = "jnp",
 ):
     """Compile-once fused epoch program for a fixed economy shape.
 
@@ -184,7 +184,7 @@ def build_fused_epoch(
     :mod:`repro.kernels.ops` (``"pallas"`` / ``"interpret"``): the kernel's
     O(nnz) scatter z replaces the blocked fold *inside the price loop*,
     while selection, settlement, and the convergence check stay on the
-    parity-exact jnp path.  ``None`` / ``"jnp"`` is the bit-parity program.
+    parity-exact jnp path.  ``"jnp"`` is the bit-parity program.
     """
     if clock.break_ties:
         raise ValueError(

@@ -367,18 +367,27 @@ def csr_padded_views(problem: CSRAuctionProblem) -> tuple[jax.Array, jax.Array]:
     """
     u, b = problem.bundle_mask.shape
     k = problem.k_bound
-    start = problem.offsets[:-1].reshape(u, b)
-    count = (problem.offsets[1:] - problem.offsets[:-1]).reshape(u, b)
-    kk = jnp.arange(k, dtype=problem.offsets.dtype)
-    live = kk[None, None, :] < count[:, :, None]
     if problem.nnz == 0:
         return (
             jnp.zeros((u, b, k), jnp.int32),
             jnp.zeros((u, b, k), jnp.float32),
         )
+    # The TPU compiler's time for this gather swings with the book's shape,
+    # up to minutes and 100 MB of code at some row counts that are not a
+    # multiple of 128.  Rows are padded to whole 128-row tiles (as empty
+    # bundles gathering element 0), gathered behind a barrier that keeps the
+    # final slice from folding back into the gather, and dropped.  Some
+    # shapes still take about 100 s.
+    up = u + (-u % 128)
+    offsets = jnp.pad(problem.offsets, (0, (up - u) * b), mode="edge")
+    start = offsets[:-1].reshape(up, b)
+    count = (offsets[1:] - offsets[:-1]).reshape(up, b)
+    kk = jnp.arange(k, dtype=offsets.dtype)
+    live = kk[None, None, :] < count[:, :, None]
     pos = jnp.clip(start[:, :, None] + kk[None, None, :], 0, problem.nnz - 1)
-    idx = jnp.where(live, problem.idx[pos], 0)
-    val = jnp.where(live, problem.val[pos], 0.0)
+    idx, val = jax.lax.optimization_barrier((problem.idx[pos], problem.val[pos]))
+    idx = jnp.where(live, idx, 0)[:u]
+    val = jnp.where(live, val, 0.0)[:u]
     return idx, val
 
 

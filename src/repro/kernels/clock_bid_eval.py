@@ -9,12 +9,15 @@ pools) this is the settlement hot loop — the paper ran it in minutes in plain
 Python at 10²×10².
 
 TPU mapping: users are blocked over the grid; each grid step loads a
-(BU, B, R⁺) bundle tile into VMEM (R⁺ = R padded to the 128-lane boundary),
-computes costs on the MXU in fp32, selects via an iota-min (no gather — TPU
-Pallas prefers the one-hot matmul form), and accumulates the tile's demand
-into a single (1, R⁺) fp32 output block that every grid step revisits
-(sequential TPU grid ⇒ safe accumulation).  Per-user winners are written to a
-(BU, 1) int32 block.  VMEM budget picks BU so the bundle tile stays ≤ ~4 MB.
+(BU, B, R⁺) bundle tile into VMEM (R⁺ = R padded to the 128-lane boundary)
+and walks its B bundles one (BU, R⁺) slice at a time: costs are lane
+reductions of bundle × price on the VPU/XLU, the cheapest valid bundle is a
+running first-minimum over the B slices, and the selected bundle comes from a
+B-step masked select (no gather, no batched one-hot matmul — Mosaic accepts
+neither here).  The tile's demand accumulates into a single (1, R⁺) fp32
+output block that every grid step revisits (sequential TPU grid ⇒ safe
+accumulation).  Per-user winners are written to a (BU, 1) int32 block.  The
+VMEM budget picks BU from the padded tile: B rounds up to 8 sublanes.
 """
 from __future__ import annotations
 
@@ -33,8 +36,13 @@ def _round_up(x: int, m: int) -> int:
 
 
 def pick_block_u(num_bundles: int, r_padded: int) -> int:
-    """Largest power-of-two user block whose bundle tile fits the VMEM budget."""
-    bu = _VMEM_TILE_BYTES // max(num_bundles * r_padded * 4, 1)
+    """Largest power-of-two user block whose bundle tile fits the VMEM budget.
+
+    A (BU, B, R⁺) block pads its last two dims to (8, 128) on the TPU, so the
+    tile holds ⌈B/8⌉·8 rows of R⁺ lanes per user; double-buffering doubles it.
+    """
+    per_user = 2 * _round_up(num_bundles, 8) * r_padded * 4
+    bu = _VMEM_TILE_BYTES // max(per_user, 1)
     bu = max(8, min(1024, bu))
     # round down to a power of two
     p = 8
@@ -45,38 +53,27 @@ def pick_block_u(num_bundles: int, r_padded: int) -> int:
 
 def _bid_eval_kernel(prices_ref, pi_ref, mask_ref, bundles_ref, z_ref, chosen_ref):
     i = pl.program_id(0)
-    bundles = bundles_ref[...].astype(jnp.float32)  # (BU, B, Rp)
-    bu, nb, rp = bundles.shape
-    prices = prices_ref[...].astype(jnp.float32).reshape(rp, 1)  # (Rp, 1)
-
-    # cost of every alternative: (BU·B, Rp) @ (Rp, 1) on the MXU
-    costs = jax.lax.dot_general(
-        bundles.reshape(bu * nb, rp),
-        prices,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(bu, nb)
-    valid = mask_ref[...] > 0  # (BU, B)
+    bu, nb, rp = bundles_ref.shape
+    prices = prices_ref[...]  # (1, Rp)
     big = jnp.float32(3.0e38)
-    costs = jnp.where(valid, costs, big)
 
-    # first-minimum index without argmin/gather (TPU-lowerable)
-    cost_hat = jnp.min(costs, axis=1)  # (BU,)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (bu, nb), 1)
-    bhat = jnp.min(jnp.where(costs == cost_hat[:, None], iota_b, nb), axis=1)
-    bhat = jnp.minimum(bhat, nb - 1)
+    # cost of every alternative, and the first cheapest valid one
+    cost_hat = jnp.full((bu, 1), big, jnp.float32)
+    bhat = jnp.zeros((bu, 1), jnp.int32)
+    for b in range(nb):
+        cost = jnp.sum(bundles_ref[:, b, :] * prices, axis=1, keepdims=True)
+        cost = jnp.where(mask_ref[:, b : b + 1] > 0, cost, big)
+        better = cost < cost_hat
+        bhat = jnp.where(better, b, bhat)
+        cost_hat = jnp.where(better, cost, cost_hat)
 
-    pi = pi_ref[...].reshape(bu)  # (BU,)
+    pi = pi_ref[...]  # (BU, 1)
     active = jnp.logical_and(cost_hat <= pi, cost_hat < big)
 
-    # selected bundle via one-hot batched matvec: (BU,B) x (BU,B,Rp) -> (BU,Rp)
-    onehot = jnp.logical_and(iota_b == bhat[:, None], active[:, None])
-    sel = jax.lax.dot_general(
-        onehot.astype(jnp.float32),
-        bundles,
-        (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # (BU, Rp)
+    # selected bundle via a B-step masked select
+    sel = jnp.zeros((bu, rp), jnp.float32)
+    for b in range(nb):
+        sel = jnp.where(jnp.logical_and(active, bhat == b), bundles_ref[:, b, :], sel)
     z_tile = jnp.sum(sel, axis=0, keepdims=True)  # (1, Rp)
 
     @pl.when(i == 0)
@@ -84,7 +81,7 @@ def _bid_eval_kernel(prices_ref, pi_ref, mask_ref, bundles_ref, z_ref, chosen_re
         z_ref[...] = jnp.zeros_like(z_ref)
 
     z_ref[...] += z_tile
-    chosen_ref[...] = jnp.where(active, bhat, -1).astype(jnp.int32).reshape(bu, 1)
+    chosen_ref[...] = jnp.where(active, bhat, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -100,14 +97,17 @@ def bid_eval(
 
     Pads U to the block size and R to the lane width; padded users carry an
     all-invalid mask (they never activate), padded resources carry zero
-    bundles and zero prices (they contribute nothing).
+    bundles and zero prices (they contribute nothing).  Bundles enter the
+    kernel as f32, whatever their dtype.
     """
     u, b, r = bundles.shape
     rp = _round_up(max(r, LANE), LANE)
     bu = pick_block_u(b, rp)
     up = _round_up(max(u, bu), bu)
 
-    bundles_p = jnp.zeros((up, b, rp), bundles.dtype).at[:u, :, :r].set(bundles)
+    bundles_p = jnp.zeros((up, b, rp), jnp.float32).at[:u, :, :r].set(
+        bundles.astype(jnp.float32)
+    )
     mask_p = jnp.zeros((up, b), jnp.int32).at[:u].set(mask.astype(jnp.int32))
     pi_p = jnp.full((up, 1), -3.0e38, jnp.float32).at[:u, 0].set(pi.astype(jnp.float32))
     prices_p = jnp.zeros((1, rp), jnp.float32).at[0, :r].set(prices.astype(jnp.float32))

@@ -1,15 +1,15 @@
-"""Jit'd public wrappers for the Pallas kernels with a pure-jnp fallback.
+"""Jit'd public wrappers for the Pallas kernels and their jnp references.
 
 ``backend``:
-  * "jnp"      — pure-JAX reference path (default off-TPU; what the multi-pod
-                 dry-run compiles, since Pallas custom calls target TPU);
-  * "pallas"   — compiled Pallas kernel (TPU);
-  * "interpret"— Pallas interpreter (CPU correctness testing).
+  * ``None`` / ``"pallas"`` — compiled Pallas kernel (TPU).  Anywhere else
+    it is refused with an error: a kernel never gives way to its reference
+    in silence;
+  * ``"interpret"`` — Pallas interpreter (CPU correctness testing);
+  * ``"jnp"`` — pure-JAX reference path (what the multi-pod dry-run
+    compiles, since Pallas custom calls target TPU).
 
-Backend selection is explicit: every path honors the requested backend (the
-old ``bid_demand_fn`` silently rerouted vector-π bids to the dense jnp proxy
-regardless of backend; vector-π is now served by the sparse kernel on every
-backend).
+Every path honors the requested backend (vector-π dense bids are served by
+the sparse kernel on every kernel backend).
 """
 from __future__ import annotations
 
@@ -26,9 +26,23 @@ from . import wkv6 as _wkv6
 
 Backend = Literal["jnp", "pallas", "interpret"]
 
+CSR_MAX_NNZ = _sbec.MAX_NNZ
 
-def default_backend() -> Backend:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+
+def _interpret(backend: Backend | None) -> bool:
+    """``interpret=`` flag for a kernel backend (``None`` is ``"pallas"``)."""
+    if backend == "interpret":
+        return True
+    if backend not in (None, "pallas"):
+        raise ValueError(f"unknown kernel backend {backend!r}")
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            "backend='pallas' compiles a Mosaic TPU kernel, but JAX's device "
+            f"is {platform!r}; pass backend='interpret' for the Pallas "
+            "interpreter or backend='jnp' for the reference"
+        )
+    return False
 
 
 def bid_eval(bundles, mask, pi, prices, backend: Backend | None = None):
@@ -37,10 +51,9 @@ def bid_eval(bundles, mask, pi, prices, backend: Backend | None = None):
     Dense scalar-π only; vector-π and sparse bundles go through
     :func:`sparse_bid_eval` (the dense Pallas kernel lacks the surplus rule).
     """
-    backend = backend or default_backend()
     if backend == "jnp":
         return ref.bid_eval(bundles, mask, pi, prices)
-    return _cbe.bid_eval(bundles, mask, pi, prices, interpret=backend == "interpret")
+    return _cbe.bid_eval(bundles, mask, pi, prices, interpret=_interpret(backend))
 
 
 def sparse_bid_eval(
@@ -51,11 +64,10 @@ def sparse_bid_eval(
     Supports scalar-π and vector-π on every backend; see
     kernels.ref.sparse_bid_eval for semantics.
     """
-    backend = backend or default_backend()
     if backend == "jnp":
         return ref.sparse_bid_eval(idx, val, mask, pi, prices, num_resources)
     return _sbe.sparse_bid_eval(
-        idx, val, mask, pi, prices, num_resources, interpret=backend == "interpret"
+        idx, val, mask, pi, prices, num_resources, interpret=_interpret(backend)
     )
 
 
@@ -77,12 +89,21 @@ def sparse_bid_eval_csr(
     skewed book moves only its true nonzeros.  ``rows`` feeds the jnp
     oracle's segment reduction; ``offsets``/``k_bound`` feed the kernel's
     segment-offset addressing.  Scalar-π and vector-π on every backend.
+
+    The kernel keeps both flat streams resident in VMEM, so a stream longer
+    than ``CSR_MAX_NNZ`` elements is refused before it reaches the compiler.
     """
-    backend = backend or default_backend()
     if backend == "jnp":
         return ref.sparse_bid_eval_csr(
             idx, val, rows, mask, pi, prices, num_resources
         )
+    if idx.shape[0] > CSR_MAX_NNZ:
+        raise ValueError(
+            f"CSR stream of {idx.shape[0]} elements exceeds the kernel's "
+            f"VMEM-resident cap of {CSR_MAX_NNZ}; use the padded kernel "
+            "(sparse_bid_eval) or backend='jnp' for a book this large"
+        )
+    interpret = _interpret(backend)
     return _sbec.sparse_bid_eval_csr(
         idx,
         val,
@@ -92,7 +113,7 @@ def sparse_bid_eval_csr(
         prices,
         num_resources,
         k_bound,
-        interpret=backend == "interpret",
+        interpret=interpret,
     )
 
 
@@ -145,21 +166,20 @@ def bid_demand_fn(backend: Backend | None = None):
     """Adapter with the auction's dense DemandFn signature (x, chosen, active)."""
 
     def demand(bundles, mask, pi, prices):
-        b = backend or default_backend()
         if pi.ndim != 1:
             # vector-π: the dense kernel lacks the surplus rule, so route
             # through the sparse kernel on the *requested* backend.
-            if b == "jnp":
+            if backend == "jnp":
                 from ..core.auction import proxy_demand
 
                 return proxy_demand(bundles, mask, pi, prices)
             idx, val = _dense_to_sparse(bundles)
             z, chosen = sparse_bid_eval(
-                idx, val, mask, pi, prices, bundles.shape[-1], backend=b
+                idx, val, mask, pi, prices, bundles.shape[-1], backend=backend
             )
             active = chosen >= 0
         else:
-            _, chosen = bid_eval(bundles, mask, pi, prices, b)
+            _, chosen = bid_eval(bundles, mask, pi, prices, backend)
             active = chosen >= 0
         sel = jnp.take_along_axis(
             bundles, jnp.maximum(chosen, 0)[:, None, None], axis=1
@@ -215,10 +235,10 @@ def fused_epoch_z_fn(backend: Backend | None, num_resources: int):
     """In-loop excess-demand evaluator for the fused epoch program.
 
     The fused epoch (:mod:`repro.core.fused`) spends almost all of its
-    clock rounds evaluating z.  ``None`` / ``"jnp"`` returns None: the fused
-    program keeps its own blocked fold, the parity-exact mirror of
+    clock rounds evaluating z.  ``"jnp"`` returns None: the fused program
+    keeps its own blocked fold, the parity-exact mirror of
     ``sparse_proxy_demand_blocked`` that EpochStats bit-parity rests on.
-    ``"pallas"`` / ``"interpret"`` return the kernel adapter's O(nnz)
+    ``None`` / ``"pallas"`` / ``"interpret"`` return the kernel adapter's O(nnz)
     scatter z for the price loop only — selection, settlement, and the
     convergence check stay on the exact jnp path, so the settled point is
     still verified and applied exactly, but the price *trajectory* is only
@@ -226,9 +246,9 @@ def fused_epoch_z_fn(backend: Backend | None, num_resources: int):
     the blocked fold's).  Use it where throughput beats bit-parity — the
     planet-scale benchmark books — never under the parity suite.
     """
-    backend = backend or "jnp"
     if backend == "jnp":
         return None
+    _interpret(backend)  # refuse a kernel that cannot run here, up front
 
     def z_fn(idx, val, mask, pi, prices):
         z, _ = sparse_bid_eval(
@@ -241,9 +261,8 @@ def fused_epoch_z_fn(backend: Backend | None, num_resources: int):
 
 def wkv6(r, k, v, w, u, state=None, chunk: int = 32, backend: Backend | None = None):
     """Chunked RWKV-6 recurrence.  See kernels.ref.wkv6 for semantics."""
-    backend = backend or default_backend()
     if backend == "jnp":
         return ref.wkv6(r, k, v, w, u, state)
     return _wkv6.wkv6(
-        r, k, v, w, u, state, chunk=chunk, interpret=backend == "interpret"
+        r, k, v, w, u, state, chunk=chunk, interpret=_interpret(backend)
     )

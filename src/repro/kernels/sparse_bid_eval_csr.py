@@ -9,22 +9,24 @@ round is the book's true nnz.
 
 TPU mapping:
 
-* users are blocked over a 1-D sequential grid, exactly like the padded
-  kernel; per block the (BU, B) ``starts``/``counts`` tiles say where each
-  bundle's elements live in the flat streams;
-* the flat idx/val streams and the (1, R⁺) price row are whole VMEM
-  residents revisited by every step (fetched once).  Bundle costs come from
-  ``k_bound`` masked passes of lane dynamic-gathers — pass k gathers element
-  k of every bundle that has one (``jnp.take`` by ``starts + k``) and
-  compare-adds it, so dead (bundle, k) slots cost a mask, not a DMA;
+* users lie on the 128 lanes, exactly like the padded kernel: per block the
+  (B, BU) ``starts``/``counts`` tiles say where each bundle's elements live
+  in the flat streams, and a loop walks the block's 128-lane slices;
+* the flat idx/val streams are whole VMEM residents, laid out as
+  (rows, 128).  The 128 users of a slice own a contiguous run of at most
+  128·B·k_bound elements, so each slice loads a window of
+  ``B·k_bound + 8`` rows starting at a scalar-prefetched, 8-aligned row;
+  element ``k`` of every bundle is fetched from that window by per-row lane
+  gathers, and dead (bundle, k) slots cost a mask, not a DMA.  The fetched
+  elements are kept for the chosen bundle's scatter;
 * selection and the compare-and-add z scatter are shared with the padded
   kernel: iota-min tie-breaks, scalar-π affordability or vector-π surplus,
-  K passes of ``z += Σ_u val_k·[idx_k == iota_r]`` into the revisited z row.
+  k_bound passes of ``z[r, l] += val_k·[idx_k == r]`` into the revisited z
+  block.
 
-Keeping the flat streams VMEM-resident caps nnz at ~1M elements per core on
-real hardware; beyond that the streams need scalar-prefetch chunking
-(ROADMAP item — this container exercises interpret mode only, like the
-padded kernel's lane dynamic-gather).
+Keeping the flat streams VMEM-resident caps nnz at ``MAX_NNZ`` (2²⁰, about
+1M elements); :mod:`repro.kernels.ops` refuses a longer stream.  Streaming
+past the cap needs scalar-prefetched window DMAs from HBM.
 """
 from __future__ import annotations
 
@@ -33,12 +35,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .sparse_bid_eval import LANE, _BIG, _round_up, pick_block_u
+from .sparse_bid_eval import (
+    LANE,
+    SUBLANE,
+    _round_up,
+    gather_rows,
+    lane_block,
+    pad_pi,
+    price_table,
+    scatter_z,
+    select_bundle,
+)
+
+MAX_NNZ = 1 << 20  # VMEM-resident stream cap (elements)
+_MIB = 1024 * 1024
+
+
+def window_rows(num_bundles: int, k_bound: int) -> int:
+    """Rows of the per-slice stream window: 128 users' B·k_bound elements
+    span at most B·k_bound + 1 rows from any start, plus 7 rows of the
+    8-row alignment of the window's first row."""
+    return _round_up(num_bundles * k_bound + SUBLANE, SUBLANE)
+
+
+def _window_gather(w_idx, w_val, rel):
+    """``(w_idx.reshape(-1)[rel], w_val.reshape(-1)[rel])`` for a
+    window-relative position tile ``rel`` (n, 128)."""
+    row = jnp.right_shift(rel, 7)
+    lane = jnp.bitwise_and(rel, LANE - 1)
+    ii = jnp.zeros(rel.shape, jnp.int32)
+    vv = jnp.zeros(rel.shape, jnp.float32)
+    for j in range(w_idx.shape[0]):
+        hit = row == j
+        src_i = jnp.broadcast_to(w_idx[j : j + 1, :], rel.shape)
+        src_v = jnp.broadcast_to(w_val[j : j + 1, :], rel.shape)
+        ii = jnp.where(hit, jnp.take_along_axis(src_i, lane, axis=1), ii)
+        vv = jnp.where(hit, jnp.take_along_axis(src_v, lane, axis=1), vv)
+    return ii, vv
 
 
 def _sparse_bid_eval_csr_kernel(
-    prices_ref,
+    base_ref,
+    table_ref,
     fidx_ref,
     fval_ref,
     pi_ref,
@@ -50,74 +90,53 @@ def _sparse_bid_eval_csr_kernel(
     *,
     scalar_pi,
     k_bound,
+    window,
 ):
     i = pl.program_id(0)
-    prices = prices_ref[...].reshape(-1)  # (Rp,)
-    rp = prices.shape[0]
-    fidx = fidx_ref[...].reshape(-1)  # (NNZp,)
-    fval = fval_ref[...].astype(jnp.float32).reshape(-1)
-    starts = starts_ref[...]  # (BU, B) int32
-    counts = counts_ref[...]  # (BU, B) int32
-    bu, nb = starts.shape
-
-    # bundle costs: k_bound masked passes of lane dynamic-gathers over the
-    # flat streams (dead slots gather element 0 and add an exact 0.0)
-    costs = jnp.zeros((bu, nb), jnp.float32)
-    for k in range(k_bound):
-        live = counts > k
-        pos = jnp.where(live, starts + k, 0)
-        ii = jnp.take(fidx, pos)  # (BU, B)
-        vv = jnp.take(fval, pos)
-        pp = jnp.take(prices, ii)
-        costs += jnp.where(live, vv * pp, 0.0)
-    valid = mask_ref[...] > 0  # (BU, B)
-
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (bu, nb), 1)
-    big = jnp.float32(_BIG)
-    if scalar_pi:
-        costs = jnp.where(valid, costs, big)
-        cost_hat = jnp.min(costs, axis=1)  # (BU,)
-        bhat = jnp.min(jnp.where(costs == cost_hat[:, None], iota_b, nb), axis=1)
-        bhat = jnp.minimum(bhat, nb - 1)
-        pi = pi_ref[...].reshape(bu)
-        active = jnp.logical_and(cost_hat <= pi, cost_hat < big)
-    else:
-        pi = pi_ref[...]  # (BU, B)
-        surplus = jnp.where(valid, pi - costs, -big)
-        s_hat = jnp.max(surplus, axis=1)  # (BU,)
-        bhat = jnp.min(jnp.where(surplus == s_hat[:, None], iota_b, nb), axis=1)
-        bhat = jnp.minimum(bhat, nb - 1)
-        active = jnp.logical_and(s_hat >= 0.0, s_hat > -big)
-
-    # chosen bundle's segment via B-step masked select, like the padded
-    # kernel's slot extraction — B is static and small
-    sel_start = jnp.zeros((bu,), jnp.int32)
-    sel_count = jnp.zeros((bu,), jnp.int32)
-    for b in range(nb):
-        hit = bhat == b
-        sel_start = jnp.where(hit, starts[:, b], sel_start)
-        sel_count = jnp.where(hit, counts[:, b], sel_count)
-    sel_count = jnp.where(active, sel_count, 0)
-
-    # one-hot-free scatter: k_bound compare-and-add passes into the z row
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (bu, rp), 1)
-    z_tile = jnp.zeros((1, rp), jnp.float32)
-    for k in range(k_bound):
-        live = sel_count > k
-        pos = jnp.where(live, sel_start + k, 0)
-        ii = jnp.take(fidx, pos)  # (BU,)
-        vv = jnp.where(live, jnp.take(fval, pos), 0.0)
-        hit_r = ii[:, None] == iota_r  # (BU, Rp)
-        z_tile += jnp.sum(
-            jnp.where(hit_r, vv[:, None], 0.0), axis=0, keepdims=True
-        )
 
     @pl.when(i == 0)
     def _init():
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    z_ref[...] += z_tile
-    chosen_ref[...] = jnp.where(active, bhat, -1).astype(jnp.int32).reshape(bu, 1)
+    slices = starts_ref.shape[-1] // LANE
+
+    def body(s, carry):
+        lanes = pl.ds(pl.multiple_of(s * LANE, LANE), LANE)
+        row0 = pl.multiple_of(base_ref[i * slices + s], SUBLANE)
+        w_idx = fidx_ref[pl.ds(row0, window), :]
+        w_val = fval_ref[pl.ds(row0, window), :]
+        starts = starts_ref[:, lanes] - row0 * LANE  # window-relative (B, 128)
+        counts = counts_ref[:, lanes]
+
+        costs = jnp.zeros(starts.shape, jnp.float32)
+        elements = []
+        for k in range(k_bound):
+            live = counts > k
+            ii, vv = _window_gather(w_idx, w_val, jnp.where(live, starts + k, 0))
+            vv = jnp.where(live, vv, 0.0)
+            costs += vv * gather_rows(table_ref, ii)
+            elements.append((ii, vv))
+        pick, bhat, active = select_bundle(
+            costs, mask_ref[:, lanes] > 0, pi_ref[:, lanes], scalar_pi
+        )
+
+        # the chosen bundle's elements (exactly one row of pick is set)
+        for ii, vv in elements:
+            sel_idx = jnp.max(jnp.where(pick, ii, 0), axis=0, keepdims=True)
+            sel_val = jnp.sum(jnp.where(pick, vv, 0.0), axis=0, keepdims=True)
+            scatter_z(z_ref, sel_idx, sel_val)
+        chosen_ref[:, lanes] = jnp.where(active, bhat, -1)
+        return carry
+
+    jax.lax.fori_loop(0, slices, body, 0)
+
+
+def pick_block_u(num_bundles: int, vector_pi: bool, num_users: int) -> int:
+    """User block (lanes) for the (B, BU) starts/counts/mask/π tiles, rows
+    padded to 8 sublanes as on the TPU."""
+    rows_b = _round_up(num_bundles, SUBLANE)
+    rows = 3 * rows_b + (rows_b if vector_pi else SUBLANE) + SUBLANE
+    return lane_block(4 * rows, num_users)
 
 
 @functools.partial(
@@ -137,66 +156,84 @@ def sparse_bid_eval_csr(
 ) -> tuple[jax.Array, jax.Array]:
     """Fused CSR proxy evaluation. Returns (z (R,), chosen (U,), -1 = out).
 
-    ``k_bound`` is the static per-bundle nnz ceiling (the loop extent).
-    Pads U to the block size and R/nnz to the lane width; padded users carry
-    zero counts, an all-invalid mask, and π = −∞, so they never activate and
-    scatter nothing.
+    ``k_bound`` is the static per-bundle nnz ceiling (the loop extent), and
+    ``offsets`` must be non-decreasing — bundle-major CSR.  Pads U to the
+    block size; padded users carry zero counts, an all-invalid mask, and
+    π = −3e38, so they never activate and scatter nothing.
     """
     u, b = mask.shape
     r = num_resources
-    rp = _round_up(max(r, LANE), LANE)
-    bu = pick_block_u(b, k_bound, rp)
-    up = _round_up(max(u, bu), bu)
     nnz = idx.shape[0]
-    nnzp = _round_up(max(nnz, LANE), LANE)
     scalar_pi = pi.ndim == 1
+    bu = pick_block_u(b, not scalar_pi, u)
+    up = _round_up(max(u, 1), bu)
+    rz = _round_up(max(r, 1), SUBLANE)
+    window = window_rows(b, k_bound)
+    stream_rows = _round_up(-(-nnz // LANE), SUBLANE) + window
 
-    starts = offsets[:-1].reshape(u, b).astype(jnp.int32)
-    counts = (offsets[1:] - offsets[:-1]).reshape(u, b).astype(jnp.int32)
-    starts_p = jnp.zeros((up, b), jnp.int32).at[:u].set(starts)
-    counts_p = jnp.zeros((up, b), jnp.int32).at[:u].set(counts)
-    mask_p = jnp.zeros((up, b), jnp.int32).at[:u].set(mask.astype(jnp.int32))
-    fidx_p = jnp.zeros((1, nnzp), jnp.int32).at[0, :nnz].set(idx.astype(jnp.int32))
-    fval_p = jnp.zeros((1, nnzp), jnp.float32).at[0, :nnz].set(
+    offsets = offsets.astype(jnp.int32)
+    starts = offsets[:-1].reshape(u, b).T
+    counts = (offsets[1:] - offsets[:-1]).reshape(u, b).T
+    starts_t = jnp.zeros((b, up), jnp.int32).at[:, :u].set(starts)
+    counts_t = jnp.zeros((b, up), jnp.int32).at[:, :u].set(counts)
+    mask_t = jnp.zeros((b, up), jnp.int32).at[:, :u].set(mask.astype(jnp.int32).T)
+    pi_t = pad_pi(pi, u, up)
+    table = price_table(prices, r)
+    fidx = jnp.zeros((stream_rows * LANE,), jnp.int32).at[:nnz].set(
+        idx.astype(jnp.int32)
+    )
+    fval = jnp.zeros((stream_rows * LANE,), jnp.float32).at[:nnz].set(
         val.astype(jnp.float32)
     )
-    if scalar_pi:
-        pi_p = jnp.full((up, 1), -3.0e38, jnp.float32).at[:u, 0].set(
-            pi.astype(jnp.float32)
-        )
-        pi_spec = pl.BlockSpec((bu, 1), lambda i: (i, 0))
-    else:
-        pi_p = jnp.full((up, b), -3.0e38, jnp.float32).at[:u].set(
-            pi.astype(jnp.float32)
-        )
-        pi_spec = pl.BlockSpec((bu, b), lambda i: (i, 0))
-    prices_p = jnp.zeros((1, rp), jnp.float32).at[0, :r].set(
-        prices.astype(jnp.float32)
-    )
+    # first window row of each 128-user slice: its first element's row,
+    # aligned down to 8 (slices past U start at the stream's end)
+    first = jnp.minimum(jnp.arange(up // LANE, dtype=jnp.int32) * LANE, u) * b
+    base = offsets[first] // LANE // SUBLANE * SUBLANE
 
-    grid = (up // bu,)
-    z, chosen = pl.pallas_call(
-        functools.partial(
-            _sparse_bid_eval_csr_kernel, scalar_pi=scalar_pi, k_bound=k_bound
-        ),
-        grid=grid,
+    stream_bytes = 2 * stream_rows * LANE * 4
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(up // bu,),
         in_specs=[
-            pl.BlockSpec((1, rp), lambda i: (0, 0)),  # prices: broadcast
-            pl.BlockSpec((1, nnzp), lambda i: (0, 0)),  # flat idx: resident
-            pl.BlockSpec((1, nnzp), lambda i: (0, 0)),  # flat val: resident
-            pi_spec,  # pi
-            pl.BlockSpec((bu, b), lambda i: (i, 0)),  # mask
-            pl.BlockSpec((bu, b), lambda i: (i, 0)),  # starts
-            pl.BlockSpec((bu, b), lambda i: (i, 0)),  # counts
+            pl.BlockSpec(table.shape, lambda i, base: (0, 0)),  # price table
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # flat idx: resident
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # flat val: resident
+            pl.BlockSpec((pi_t.shape[0], bu), lambda i, base: (0, i)),  # pi
+            pl.BlockSpec((b, bu), lambda i, base: (0, i)),  # mask
+            pl.BlockSpec((b, bu), lambda i, base: (0, i)),  # starts
+            pl.BlockSpec((b, bu), lambda i, base: (0, i)),  # counts
         ],
         out_specs=[
-            pl.BlockSpec((1, rp), lambda i: (0, 0)),  # z: revisited/accumulated
-            pl.BlockSpec((bu, 1), lambda i: (i, 0)),  # chosen
+            pl.BlockSpec((rz, LANE), lambda i, base: (0, 0)),  # z: revisited
+            pl.BlockSpec((1, bu), lambda i, base: (0, i)),  # chosen
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, rp), jnp.float32),
-            jax.ShapeDtypeStruct((up, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(prices_p, fidx_p, fval_p, pi_p, mask_p, starts_p, counts_p)
-    return z[0, :r], chosen[:u, 0]
+    )
+    with jax.enable_x64(False):  # Mosaic lowers no 64-bit value
+        z, chosen = pl.pallas_call(
+            functools.partial(
+                _sparse_bid_eval_csr_kernel,
+                scalar_pi=scalar_pi,
+                k_bound=k_bound,
+                window=window,
+            ),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((rz, LANE), jnp.float32),
+                jax.ShapeDtypeStruct((1, up), jnp.int32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=stream_bytes + 32 * _MIB,
+            ),
+            interpret=interpret,
+        )(
+            base,
+            table,
+            fidx.reshape(stream_rows, LANE),
+            fval.reshape(stream_rows, LANE),
+            pi_t,
+            mask_t,
+            starts_t,
+            counts_t,
+        )
+    return z.sum(axis=1)[:r], chosen[0, :u]

@@ -835,6 +835,9 @@ def main(argv=None):
 
     import os
 
+    from .. import compile_cache
+
+    compile_cache.configure()
     eco = fleet_economy(args.agents, args.clusters, seed=args.seed)
     cfg = ServiceConfig()
     if args.durable_dir:

@@ -1,4 +1,3 @@
-from .compat import shard_map
 from .specs import (
     ACT_RULES,
     replicate,
@@ -24,6 +23,5 @@ __all__ = [
     "set_act_rules",
     "set_mesh",
     "shard",
-    "shard_map",
     "use_mesh",
 ]

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding import shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable,  # (stage_params, x_mb) -> y_mb
@@ -91,7 +89,7 @@ def make_pipelined_loss(
 
         specs_stages = jax.tree_util.tree_map(lambda _: P(axis_name), params["stages"])
         specs_head = jax.tree_util.tree_map(lambda _: P(), params["head"])
-        out = shard_map(
+        out = jax.shard_map(
             shmapped,
             mesh=mesh,
             in_specs=(specs_stages, specs_head, P(), P()),

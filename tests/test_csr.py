@@ -252,6 +252,25 @@ def test_ops_csr_backend_dispatch():
     np.testing.assert_allclose(np.asarray(za), np.asarray(zb), rtol=1e-4, atol=1e-4)
 
 
+def test_ops_csr_refuses_stream_over_vmem_cap():
+    """The kernel keeps both flat streams resident in VMEM: a longer stream
+    is refused before anything is traced or compiled."""
+    nnz = ops.CSR_MAX_NNZ + 1
+    u, b = 1, 1
+    idx = jnp.zeros((nnz,), jnp.int32)
+    val = jnp.zeros((nnz,), jnp.float32)
+    rows = jnp.zeros((nnz,), jnp.int32)
+    offsets = jnp.asarray([0, nnz], jnp.int32)
+    mask = jnp.ones((u, b), bool)
+    pi = jnp.ones((u,), jnp.float32)
+    prices = jnp.ones((3,), jnp.float32)
+    for backend in (None, "pallas", "interpret"):
+        with pytest.raises(ValueError, match="VMEM-resident cap"):
+            ops.sparse_bid_eval_csr(
+                idx, val, rows, offsets, mask, pi, prices, 3, nnz, backend=backend
+            )
+
+
 # ---------------------------------------------------------------------------
 # end-to-end: the clock on CSR books
 # ---------------------------------------------------------------------------

@@ -46,6 +46,22 @@ def test_ops_backend_dispatch():
     np.testing.assert_allclose(np.asarray(za), np.asarray(zb), rtol=1e-4, atol=1e-4)
 
 
+def test_ops_without_backend_refuses_off_tpu():
+    """No backend means the compiled kernel; off the TPU that is a clear
+    error, never a silent switch to the jnp reference."""
+    import jax
+
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    bundles, mask, pi, prices = map(jnp.asarray, _bid_case(16, 2, 6, np.float32))
+    with pytest.raises(RuntimeError, match="backend='interpret'"):
+        ops.bid_eval(bundles, mask, pi, prices)
+    with pytest.raises(RuntimeError, match="Mosaic TPU kernel"):
+        ops.fused_epoch_z_fn(None, 6)
+    with pytest.raises(ValueError, match="unknown kernel backend"):
+        ops.bid_eval(bundles, mask, pi, prices, backend="cuda")
+
+
 def _wkv_case(T, H, K, V, dtype=np.float32, strong_decay=True):
     r = RNG.normal(size=(T, H, K)).astype(dtype)
     k = (RNG.normal(size=(T, H, K)) * 0.5).astype(dtype)

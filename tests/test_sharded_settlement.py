@@ -1,4 +1,4 @@
-"""Multi-device clock settlement: shard_map compat + bit-identical sharding.
+"""Multi-device clock settlement: bit-identical sharding.
 
 The acceptance bar for the sharded path is *bit*-identity, not tolerance:
 ``sharded_clock_auction`` on 2/4/8 virtual CPU devices must produce the same
@@ -22,48 +22,6 @@ def _run(script, timeout=580):
         [sys.executable, "-c", script], capture_output=True, text=True,
         env=env, cwd=os.getcwd(), timeout=timeout,
     )
-
-
-# ---------------------------------------------------------------------------
-# shard_map compat wrapper
-# ---------------------------------------------------------------------------
-
-
-def test_compat_shard_map_resolves_on_this_jax():
-    """The wrapper must resolve an implementation on the pinned jax (which
-    has no top-level jax.shard_map) and accept either check-flag spelling."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from repro.sharding import shard_map
-
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("users",))
-    x = jnp.arange(8, dtype=jnp.float32)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        y = shard_map(
-            lambda a: a * 2, mesh=mesh, in_specs=P("users"), out_specs=P("users"),
-            **kw,
-        )(x)
-        np.testing.assert_array_equal(np.asarray(y), np.asarray(x) * 2)
-
-
-def test_compat_shard_map_rejects_conflicting_flags():
-    from jax.sharding import PartitionSpec as P
-
-    from repro.sharding import shard_map
-
-    with pytest.raises(ValueError):
-        shard_map(lambda a: a, in_specs=P(), out_specs=P(), check_vma=True, check_rep=False)
-
-
-def test_compat_shard_map_rejects_unknown_kwargs():
-    from jax.sharding import PartitionSpec as P
-
-    from repro.sharding import shard_map
-
-    with pytest.raises(TypeError):
-        shard_map(lambda a: a, in_specs=P(), out_specs=P(), definitely_not_a_real_kwarg=1)
 
 
 # ---------------------------------------------------------------------------
