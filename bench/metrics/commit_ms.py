@@ -1,0 +1,7 @@
+"""Commit: ``market.commit`` time (the durable commit: checkpoint, WAL
+truncate or sync) per window tick, from the program's own spans."""
+from bench import program_spans
+
+
+def read(run):
+    return program_spans.per_tick_ms(run, "market.commit")

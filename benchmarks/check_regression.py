@@ -15,8 +15,8 @@ ratio, verdict, and the recent record history with git SHAs — is appended
 to the job summary, so the settlement perf trajectory is readable from the
 Actions UI without downloading the artifact.
 
-Records are stamped with ``workload`` (the ECONOMY_EPOCH_*/MARKET_SERVE_*
-env overrides in effect) and ``host`` (BENCH_HOST_TAG / "github-ci" /
+Records are stamped with ``workload`` (the ECONOMY_EPOCH_* env overrides
+in effect) and ``host`` (BENCH_HOST_TAG / "github-ci" /
 hostname) by ``run.py --json``; the guard only compares records whose
 (name, workload, host) identity matches the latest record's, and loudly
 skips a benchmark whose latest record has no like-for-like baseline —

@@ -706,355 +706,6 @@ def bid_eval_csr():
     return us_csr, round(us_pad / us_csr, 1)
 
 
-def market_serve():
-    """Always-on market service under heavy churn (ISSUE 8 tentpole): a
-    100k-agent book served by repro.serve.market.MarketService, with
-    1%/5%/20% of agents re-pricing their resting bid per tick.  Measures
-    sustained bid ingestion (bids/s through submit), p99 tick latency per
-    churn level, and the epoch-prep speedup of the incremental O(Δ) book
-    (drain + device row-scatter) over a from-scratch full repack + upload.
-    us_per_call: p99 tick latency at 1% churn.  derived: prep speedup at 1%
-    churn (asserted ≥ 5×)."""
-    import jax
-    from repro.core.markets import fleet_economy
-    from repro.core.types import MarketBook
-    from repro.serve.market import BidDelta, MarketService
-
-    n = int(os.environ.get("MARKET_SERVE_AGENTS", 100_000))
-    ticks = int(os.environ.get("MARKET_SERVE_TICKS", 6))
-    eco = fleet_economy(n, 6, seed=0)
-    t0 = time.perf_counter()
-    svc = MarketService.from_economy(eco)
-    load_s = time.perf_counter() - t0
-    print(
-        f"# market_serve: {svc.book.num_rows} rows bulk-loaded in "
-        f"{load_s:.2f}s ({svc.book.rows_cap} slots)",
-        file=sys.stderr,
-    )
-    keys, idx_rows, val_rows, mask_rows, pi_rows = eco.export_bid_rows()
-    live = np.flatnonzero(mask_rows.any(axis=1))
-    rng = np.random.default_rng(0)
-
-    def deltas(frac, tick):
-        d = max(1, int(frac * n))
-        pick = rng.choice(live, size=min(d, live.size), replace=False)
-        scale = rng.uniform(0.9, 1.1, size=pick.size).astype(np.float32)
-        out = []
-        for j, i in enumerate(pick):
-            bundles = [
-                (idx_rows[i, b], val_rows[i, b])
-                for b in np.flatnonzero(mask_rows[i])
-            ]
-            out.append(
-                BidDelta(keys[i], bundles, pi_rows[i][mask_rows[i]] * scale[j])
-            )
-        return out
-
-    def _sync(problem):
-        jax.block_until_ready(
-            (problem.idx, problem.val, problem.bundle_mask, problem.pi)
-        )
-
-    svc.tick()  # compile + settle the cold book once
-
-    # -- sustained ingestion: bids/s through the validating submit path ------
-    batch = deltas(0.05, 0)
-    t0 = time.perf_counter()
-    for dl in batch:
-        svc.submit(dl)
-    ingest_s = time.perf_counter() - t0
-    bids_per_s = len(batch) / ingest_s
-    svc.tick()
-
-    # -- epoch-prep: incremental drain + O(Δ) device scatter vs full repack --
-    incr = []
-    for t in range(3):
-        for dl in deltas(0.01, t):
-            svc.submit(dl)
-        t0 = time.perf_counter()
-        svc._drain()
-        _sync(svc.book.device_problem())
-        incr.append(time.perf_counter() - t0)
-    us_incr = min(incr) * 1e6
-
-    op_keys = [k for k in svc.book._key_slot if str(k).startswith("op-")]
-    op_rows = [svc.book._accounts[k] for k in op_keys]
-    full = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        fresh = MarketBook(
-            svc.book.base_cost, svc.book.num_bundles, svc.book.k_bound,
-            svc.book.rows_cap,
-        )
-        for k, (bundles, pi) in zip(op_keys, op_rows):
-            fresh.upsert(k, bundles, pi)
-        fresh.upsert_rows(keys, idx_rows, val_rows, mask_rows, pi_rows)
-        _sync(fresh.problem())
-        full.append(time.perf_counter() - t0)
-    us_full = min(full) * 1e6
-    speedup = us_full / max(us_incr, 1e-9)
-
-    # -- p99 tick latency per churn level ------------------------------------
-    p99_by_churn = {}
-    for frac in (0.01, 0.05, 0.20):
-        walls = []
-        for t in range(ticks):
-            for dl in deltas(frac, t):
-                svc.submit(dl)
-            t0 = time.perf_counter()
-            s = svc.tick()
-            walls.append(time.perf_counter() - t0)
-        p99_by_churn[frac] = float(np.percentile(walls, 99)) * 1e6
-        print(
-            f"# market_serve: churn {frac:.0%} — p99 tick "
-            f"{p99_by_churn[frac] / 1e3:.0f} ms, last rounds {s.rounds}, "
-            f"converged {s.converged}",
-            file=sys.stderr,
-        )
-    svc.book.parity_check()  # the benchmark book must match its oracle
-    print(
-        f"# market_serve: ingest {bids_per_s:,.0f} bids/s; epoch-prep "
-        f"incremental {us_incr / 1e3:.1f} ms vs full repack "
-        f"{us_full / 1e3:.1f} ms = {speedup:.1f}x at 1% churn",
-        file=sys.stderr,
-    )
-    assert speedup >= 5.0, (
-        f"incremental epoch-prep speedup {speedup:.1f}x < 5x over full repack"
-    )
-    return p99_by_churn[0.01], round(speedup, 1)
-
-
-def market_recover():
-    """Durable market service (ISSUE 9 tentpole): WAL ingestion overhead and
-    crash-recovery wall time at a 100k-row book.  Measures the per-submit
-    cost of the journaled path (default "flush" mode, asserted < 2x the
-    no-WAL submit path, plus the optional per-append-fsync mode for the
-    power-failure-durability trade-off), the tick-boundary checkpoint cost,
-    and full recovery wall time (restore latest checkpoint + replay the WAL
-    tail through validation).  us_per_call: recovery wall.  derived: WAL-on
-    ingestion overhead ratio (asserted < 2x)."""
-    import shutil
-    import tempfile
-
-    from repro.core.markets import fleet_economy
-    from repro.serve import ServiceConfig
-    from repro.serve.market import BidDelta, MarketService
-
-    n = int(os.environ.get("MARKET_RECOVER_AGENTS", 100_000))
-    tail = int(os.environ.get("MARKET_RECOVER_TAIL", 5_000))
-    eco = fleet_economy(n, 6, seed=0)
-    d = tempfile.mkdtemp(prefix="market_recover_")
-    try:
-        cfg = ServiceConfig(
-            wal_path=os.path.join(d, "market.wal"),
-            checkpoint_dir=os.path.join(d, "ckpt"),
-        )
-        t0 = time.perf_counter()
-        svc = MarketService.from_economy(eco, config=cfg)
-        load_s = time.perf_counter() - t0
-        print(
-            f"# market_recover: {svc.book.num_rows} rows bulk-loaded + "
-            f"bootstrap checkpoint in {load_s:.2f}s",
-            file=sys.stderr,
-        )
-        keys, idx_rows, val_rows, mask_rows, pi_rows = eco.export_bid_rows()
-        live = np.flatnonzero(mask_rows.any(axis=1))
-        rng = np.random.default_rng(0)
-
-        def deltas(count, salt):
-            pick = rng.choice(live, size=min(count, live.size), replace=False)
-            out = []
-            for j, i in enumerate(pick):
-                bundles = [
-                    (idx_rows[i, b], val_rows[i, b])
-                    for b in np.flatnonzero(mask_rows[i])
-                ]
-                out.append(BidDelta(
-                    keys[i], bundles,
-                    pi_rows[i][mask_rows[i]] * (0.95 + 0.001 * ((j + salt) % 100)),
-                ))
-            return out
-
-        def time_ingest(batch):
-            t0 = time.perf_counter()
-            for dl in batch:
-                svc.submit(dl)
-            return (time.perf_counter() - t0) / len(batch) * 1e6
-
-        # -- WAL ingestion overhead vs the bare submit path ------------------
-        # same service, same book, same pending state: detach the WAL for the
-        # baseline so the ONLY difference is the journaled write
-        us_wal = time_ingest(deltas(tail, 0))
-        wal = svc._wal
-        svc._wal = None
-        us_bare = time_ingest(deltas(tail, 1))
-        svc._wal = wal
-        overhead = us_wal / max(us_bare, 1e-9)
-        # per-append fsync mode: power-failure durable, priced separately
-        wal.sync_mode = "fsync"
-        us_fsync = time_ingest(deltas(200, 2))
-        wal.sync_mode = "flush"
-
-        # -- tick-boundary commit: settle + checkpoint + WAL compaction ------
-        t0 = time.perf_counter()
-        svc.tick()
-        tick_s = time.perf_counter() - t0
-
-        # -- crash + recovery: restore checkpoint, replay the WAL tail -------
-        for dl in deltas(tail, 3):
-            svc.submit(dl)
-        pend = svc.pending
-        del svc  # hard drop: no drain, no checkpoint
-        t0 = time.perf_counter()
-        svc = MarketService.from_economy(eco, config=cfg)
-        recover_s = time.perf_counter() - t0
-        assert svc.restored_step is not None, "recovery never found a checkpoint"
-        assert svc.pending == pend, (
-            f"recovery lost pending bids: {svc.pending} != {pend}"
-        )
-        svc.book.parity_check()  # the recovered book must match its oracle
-
-        print(
-            f"# market_recover: submit {us_bare:.1f} us bare, {us_wal:.1f} us "
-            f"WAL(flush) = {overhead:.2f}x, {us_fsync:.0f} us WAL(fsync); "
-            f"commit tick {tick_s:.2f}s; recovery "
-            f"{recover_s * 1e3:.0f} ms ({svc.replayed_records} records replayed)",
-            file=sys.stderr,
-        )
-        assert overhead < 2.0, (
-            f"WAL ingestion overhead {overhead:.2f}x >= 2x the no-WAL path"
-        )
-        return recover_s * 1e6, round(overhead, 2)
-    finally:
-        shutil.rmtree(d, ignore_errors=True)
-
-
-def market_commit():
-    """Low-latency durable commits (ISSUE 10 tentpole): the binding-tick
-    commit wall at a 100k-row book under 1%/5%/20% churn, three ways —
-    the PR-9 style *full* checkpoint (every commit exports the whole
-    book), the *incremental* dirty-row delta record (O(Δ) in the churn),
-    and the *async* background commit (the tick pays only snapshot +
-    dispatch; durability is settled by the next tick's wait).  Each cycle
-    churns the book, drains it with a tick (checkpoint_interval is parked
-    high so the tick itself does not commit), then times one commit
-    through the service's own sync commit sequence (save + WAL truncate).
-    Override the book size with MARKET_COMMIT_AGENTS.
-    us_per_call: incremental commit wall at 1% churn.  derived:
-    full/incremental commit speedup at 1% churn (asserted >= 3x, the
-    acceptance bound vs the PR-9 full-export commit)."""
-    import shutil
-    import tempfile
-
-    from repro.core.markets import fleet_economy
-    from repro.serve import ServiceConfig
-    from repro.serve.market import BidDelta, MarketService
-
-    n = int(os.environ.get("MARKET_COMMIT_AGENTS", 100_000))
-    eco = fleet_economy(n, 6, seed=0)
-    d = tempfile.mkdtemp(prefix="market_commit_")
-    try:
-        cfg = ServiceConfig(
-            wal_path=os.path.join(d, "market.wal"),
-            checkpoint_dir=os.path.join(d, "ckpt"),
-            # ticks drain and settle but never auto-commit: the commit is
-            # timed explicitly below, isolated from settlement wall
-            checkpoint_interval=1_000_000_000,
-            checkpoint_full_every=1_000_000_000,
-        )
-        t0 = time.perf_counter()
-        svc = MarketService.from_economy(eco, config=cfg)
-        print(
-            f"# market_commit: {svc.book.num_rows} rows bulk-loaded + "
-            f"bootstrap checkpoint in {time.perf_counter() - t0:.2f}s",
-            file=sys.stderr,
-        )
-        keys, idx_rows, val_rows, mask_rows, pi_rows = eco.export_bid_rows()
-        live = np.flatnonzero(mask_rows.any(axis=1))
-        rng = np.random.default_rng(0)
-
-        def churn(frac):
-            pick = rng.choice(
-                live, size=min(max(1, int(frac * n)), live.size), replace=False
-            )
-            scale = rng.uniform(0.9, 1.1, size=pick.size).astype(np.float32)
-            for j, i in enumerate(pick):
-                bundles = [
-                    (idx_rows[i, b], val_rows[i, b])
-                    for b in np.flatnonzero(mask_rows[i])
-                ]
-                svc.submit(BidDelta(
-                    keys[i], bundles, pi_rows[i][mask_rows[i]] * scale[j]
-                ))
-
-        def sync_commit(force_full=False):
-            """The service's own sync commit sequence, timed in isolation."""
-            t0 = time.perf_counter()
-            svc._ckpt.save(svc, block=True, force_full=force_full)
-            svc._durable_wal_offset = svc._wal_drained_offset
-            svc._truncate_wal()
-            return time.perf_counter() - t0
-
-        svc.tick()  # compile + settle the cold book once
-        sync_commit()  # establish the base full record
-
-        incr_by_churn = {}
-        for frac in (0.01, 0.05, 0.20):
-            walls = []
-            for _ in range(2):
-                churn(frac)
-                svc.tick()
-                walls.append(sync_commit())
-            incr_by_churn[frac] = min(walls) * 1e6
-            print(
-                f"# market_commit: churn {frac:.0%} — incremental commit "
-                f"{incr_by_churn[frac] / 1e3:.1f} ms",
-                file=sys.stderr,
-            )
-
-        # PR-9 baseline shape: every commit exports the full book
-        full_walls = []
-        for _ in range(2):
-            churn(0.01)
-            svc.tick()
-            full_walls.append(sync_commit(force_full=True))
-        us_full = min(full_walls) * 1e6
-
-        # async commit: the tick-visible wall is snapshot + dispatch; the
-        # write itself overlaps the next tick and is settled by its wait
-        disp_walls, wait_walls = [], []
-        for _ in range(2):
-            churn(0.01)
-            svc.tick()
-            t0 = time.perf_counter()
-            svc._ckpt.save_async(svc)
-            disp_walls.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            payload, err = svc._ckpt.wait_commit(svc)
-            wait_walls.append(time.perf_counter() - t0)
-            assert err is None and payload is not None
-            svc._durable_wal_offset = payload.wal_offset
-            svc._truncate_wal()
-        us_disp = min(disp_walls) * 1e6
-
-        svc.book.parity_check()
-        speedup = us_full / max(incr_by_churn[0.01], 1e-9)
-        print(
-            f"# market_commit: full {us_full / 1e3:.0f} ms vs incremental "
-            f"{incr_by_churn[0.01] / 1e3:.1f} ms = {speedup:.1f}x at 1% churn; "
-            f"async dispatch {us_disp / 1e3:.1f} ms "
-            f"(+{min(wait_walls) * 1e3:.1f} ms settled next tick)",
-            file=sys.stderr,
-        )
-        assert speedup >= 3.0, (
-            f"incremental commit speedup {speedup:.1f}x < 3x over the "
-            "full-export commit"
-        )
-        return incr_by_churn[0.01], round(speedup, 1)
-    finally:
-        shutil.rmtree(d, ignore_errors=True)
-
-
 def roofline_summary():
     """§Roofline — aggregate the dry-run matrix artifacts.
     derived: count of single-pod cells whose compile succeeded."""
@@ -1096,9 +747,6 @@ BENCHES = {
     "bid_eval_round": bid_eval_round,
     "bid_eval_sparse": bid_eval_sparse,
     "bid_eval_csr": bid_eval_csr,
-    "market_serve": market_serve,
-    "market_recover": market_recover,
-    "market_commit": market_commit,
     "roofline_summary": roofline_summary,
 }
 
@@ -1145,9 +793,7 @@ def _load_records(path: str) -> list:
 # env knobs that reshape a benchmark's workload — any of these being set means
 # the numbers are not comparable to a run without them, so they go in the
 # record's identity stamp
-_WORKLOAD_ENV_PREFIXES = (
-    "ECONOMY_EPOCH_", "MARKET_SERVE_", "MARKET_RECOVER_", "MARKET_COMMIT_",
-)
+_WORKLOAD_ENV_PREFIXES = ("ECONOMY_EPOCH_",)
 
 
 def _workload() -> dict:

@@ -44,6 +44,7 @@ import numpy as np
 
 from ..core.economy import EpochStats
 from ..core.types import MarketBook
+from ..tracing import span
 from .store import CheckpointStore
 
 # EpochStats fields that are numpy arrays (stacked across the history ring);
@@ -138,17 +139,12 @@ class ServiceCheckpointer(CheckpointStore):
             ),
         }
 
-    def _snapshot(self, svc, force_full: bool = False, copy: bool = False):
-        """Capture one commit's state as a :class:`_Payload`.
-
-        Advances the chain state and clears the book's dirty set / the
-        service's history-tail counters — :meth:`_rollback` is the undo if
-        the write never becomes durable.
-        """
+    def _next_is_full(self, svc, force_full: bool = False) -> bool:
+        """Whether the record cut now at ``svc``'s boundary is a full one."""
         step = int(svc.epoch)
         n_prices = int(getattr(svc, "_prices_since_ckpt", 0))
         n_stats = int(getattr(svc, "_stats_since_ckpt", 0))
-        full = (
+        return (
             force_full
             or self._force_full
             or self._last_step is None
@@ -164,6 +160,18 @@ class ServiceCheckpointer(CheckpointStore):
             or n_prices > len(svc.price_history)
             or n_stats > len(svc.stats_history)
         )
+
+    def _snapshot(self, svc, full: bool, copy: bool = False) -> _Payload:
+        """Capture one commit's state as a :class:`_Payload` of the kind
+        :meth:`_next_is_full` chose.
+
+        Advances the chain state and clears the book's dirty set / the
+        service's history-tail counters — :meth:`_rollback` is the undo if
+        the write never becomes durable.
+        """
+        step = int(svc.epoch)
+        n_prices = int(getattr(svc, "_prices_since_ckpt", 0))
+        n_stats = int(getattr(svc, "_stats_since_ckpt", 0))
         prev = (self._last_step, self._deltas_since_full, self._base_step)
 
         if full:
@@ -253,18 +261,19 @@ class ServiceCheckpointer(CheckpointStore):
     def _write_payload(self, payload: _Payload) -> None:
         prefix = _FULL if payload.kind == "full" else _DELTA
         probe = "mid_compaction" if payload.kind == "full" else "mid_delta"
-        self.write_record(
-            prefix,
-            payload.step,
-            payload.tree,
-            metadata=payload.meta,
-            pre_replace=lambda: payload.hook(probe),
-        )
-        if payload.kind == "full":
-            # the new full supersedes the old chain; the probe below kills
-            # between the replace and the prune (both generations on disk)
-            payload.hook("post_compaction")
-        self._prune()
+        with span("checkpoint.write"):
+            self.write_record(
+                prefix,
+                payload.step,
+                payload.tree,
+                metadata=payload.meta,
+                pre_replace=lambda: payload.hook(probe),
+            )
+            if payload.kind == "full":
+                # the new full supersedes the old chain; the probe below kills
+                # between the replace and the prune (both generations on disk)
+                payload.hook("post_compaction")
+            self._prune()
 
     def save(self, svc, block: bool = True, force_full: bool = False) -> int:
         """Checkpoint at the current tick boundary; returns the step.
@@ -280,12 +289,15 @@ class ServiceCheckpointer(CheckpointStore):
             raise err
         if not block:
             return self.save_async(svc, force_full=force_full)
-        payload = self._snapshot(svc, force_full=force_full)
-        try:
-            self._write_payload(payload)
-        except BaseException:
-            self._rollback(payload, svc)
-            raise
+        full = self._next_is_full(svc, force_full)
+        with span("checkpoint.full" if full else "checkpoint.delta", step=int(svc.epoch)):
+            with span("checkpoint.snapshot"):
+                payload = self._snapshot(svc, full)
+            try:
+                self._write_payload(payload)
+            except BaseException:
+                self._rollback(payload, svc)
+                raise
         return payload.step
 
     def save_async(self, svc, force_full: bool = False) -> int:
@@ -298,7 +310,12 @@ class ServiceCheckpointer(CheckpointStore):
         _, err = self.wait_commit(svc)
         if err is not None:
             raise err
-        payload = self._snapshot(svc, force_full=force_full, copy=True)
+        full = self._next_is_full(svc, force_full)
+        # the write runs on the writer thread, under its own
+        # ``market.checkpoint.write`` span
+        with span("checkpoint.full" if full else "checkpoint.delta", step=int(svc.epoch)):
+            with span("checkpoint.snapshot"):
+                payload = self._snapshot(svc, full, copy=True)
         self._inflight = payload
 
         def work():

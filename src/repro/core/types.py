@@ -42,6 +42,8 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
+from ..tracing import span
+
 
 @dataclasses.dataclass(frozen=True)
 class ResourcePool:
@@ -1183,55 +1185,63 @@ class MarketBook:
         uploaded once; afterwards each call flushes only the slots written
         since the last sync, with the delta batch padded to a power-of-two
         bucket (idempotent duplicate writes of the first slot) so churn
-        reuses a handful of compiled scatter programs per capacity.
+        reuses a handful of compiled scatter programs per capacity.  The
+        work runs under the ``market.scatter`` span (``kind``: full, delta
+        or none; ``bucket``: the rows written).
         """
         if self._dev is None or self._dev_generation != self._generation:
-            self._dev = {
-                "idx": jnp.asarray(self.idx),
-                "val": jnp.asarray(self.val),
-                "mask": jnp.asarray(self.mask),
-                "pi": jnp.asarray(self.pi),
-            }
-            self._dev_generation = self._generation
-            self._dev_pending.clear()
+            kind, d = "full", self.rows_cap
         elif self._dev_pending:
             slots = sorted(set(self._dev_pending))
-            d = 1
+            kind, d = "delta", 1
             while d < len(slots):  # rows_cap is a power of two, so d <= rows_cap
                 d *= 2
-            padded = np.full(d, slots[0], np.int32)
-            padded[: len(slots)] = slots
-            b, k = self.num_bundles, self.k_bound
-            el = (
-                padded.astype(np.int64)[:, None, None] * (b * k)
-                + np.arange(b)[None, :, None] * k
-                + np.arange(k)[None, None, :]
-            ).reshape(d, b, k)
-            new = _csr_apply_row_deltas(
-                self._dev["idx"], self._dev["val"], self._dev["mask"],
-                self._dev["pi"], jnp.asarray(padded),
-                jnp.asarray(self.idx[el.reshape(d, -1)].reshape(d, b, k)),
-                jnp.asarray(self.val[el.reshape(d, -1)].reshape(d, b, k)),
-                jnp.asarray(self.mask[padded]),
-                jnp.asarray(self.pi[padded]),
+        else:
+            kind, d = "none", 0
+        with span("scatter", kind=kind, bucket=d):
+            if kind == "full":
+                self._dev = {
+                    "idx": jnp.asarray(self.idx),
+                    "val": jnp.asarray(self.val),
+                    "mask": jnp.asarray(self.mask),
+                    "pi": jnp.asarray(self.pi),
+                }
+                self._dev_generation = self._generation
+                self._dev_pending.clear()
+            elif kind == "delta":
+                padded = np.full(d, slots[0], np.int32)
+                padded[: len(slots)] = slots
+                b, k = self.num_bundles, self.k_bound
+                el = (
+                    padded.astype(np.int64)[:, None, None] * (b * k)
+                    + np.arange(b)[None, :, None] * k
+                    + np.arange(k)[None, None, :]
+                ).reshape(d, b, k)
+                new = _csr_apply_row_deltas(
+                    self._dev["idx"], self._dev["val"], self._dev["mask"],
+                    self._dev["pi"], jnp.asarray(padded),
+                    jnp.asarray(self.idx[el.reshape(d, -1)].reshape(d, b, k)),
+                    jnp.asarray(self.val[el.reshape(d, -1)].reshape(d, b, k)),
+                    jnp.asarray(self.mask[padded]),
+                    jnp.asarray(self.pi[padded]),
+                )
+                self._dev = dict(zip(("idx", "val", "mask", "pi"), new))
+                self._dev_pending.clear()
+            rows, offsets = _book_static_layout(
+                self.rows_cap, self.num_bundles, self.k_bound
             )
-            self._dev = dict(zip(("idx", "val", "mask", "pi"), new))
-            self._dev_pending.clear()
-        rows, offsets = _book_static_layout(
-            self.rows_cap, self.num_bundles, self.k_bound
-        )
-        return CSRAuctionProblem(
-            idx=self._dev["idx"],
-            val=self._dev["val"],
-            rows=rows,
-            offsets=offsets,
-            bundle_mask=self._dev["mask"],
-            pi=self._dev["pi"],
-            base_cost=jnp.asarray(self.base_cost),
-            supply_scale=jnp.asarray(self.supply_scale()),
-            num_resources=self.num_resources,
-            k_bound=self.k_bound,
-        )
+            return CSRAuctionProblem(
+                idx=self._dev["idx"],
+                val=self._dev["val"],
+                rows=rows,
+                offsets=offsets,
+                bundle_mask=self._dev["mask"],
+                pi=self._dev["pi"],
+                base_cost=jnp.asarray(self.base_cost),
+                supply_scale=jnp.asarray(self.supply_scale()),
+                num_resources=self.num_resources,
+                k_bound=self.k_bound,
+            )
 
     # -- full-repack oracle -------------------------------------------------
 

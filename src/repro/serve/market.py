@@ -1,7 +1,7 @@
 """Always-on market service: durable streaming ingestion over a persistent book.
 
     PYTHONPATH=src python -m repro.serve.market --agents 2000 --clusters 4 \
-        --ticks 3 --churn 0.05 --durable-dir /tmp/market
+        --ticks 3 --churn 0.05 --durable-dir /tmp/market [--profile DIR]
 
 The paper runs its clock auction "at regular time intervals" so prices
 fluctuate like a real economy — which only works if the next round *will*
@@ -73,6 +73,7 @@ from ..core.economy import Economy, EpochStats
 from ..core.faults import FaultModel
 from ..core.reserve import DEFAULT_WEIGHTING, reserve_prices
 from ..core.types import MarketBook
+from ..tracing import span
 from .wal import WriteAheadLog
 
 
@@ -346,41 +347,46 @@ class MarketService:
         return False and surface in the next tick's EpochStats."""
         if delta.is_withdraw:
             return self.withdraw(delta.key)
-        self._wal_append(_submit_record(delta))
-        if delta.key not in self._pending and len(self._pending) >= self.max_pending:
-            self._deferred += 1
-            return False
-        try:
-            row = self.book._pack_row(delta.bundles, delta.pi)
-        except (ValueError, TypeError):
-            self._rejected += 1
-            return False
-        if row[1].size and float(np.abs(row[1]).max()) > self.max_quantity:
-            self._rejected += 1
-            return False
-        raw = (
-            tuple(
-                (np.array(ii, np.int32), np.array(vv, np.float32))
-                for ii, vv in delta.bundles
-            ),
-            np.asarray(delta.pi, np.float32),
-        )
-        self._pending[delta.key] = ("upsert", row, raw)
-        return True
+        with span("submit", epoch=self.epoch):
+            with span("wal_append"):
+                self._wal_append(_submit_record(delta))
+            if delta.key not in self._pending and len(self._pending) >= self.max_pending:
+                self._deferred += 1
+                return False
+            with span("validate"):
+                try:
+                    row = self.book._pack_row(delta.bundles, delta.pi)
+                except (ValueError, TypeError):
+                    self._rejected += 1
+                    return False
+                if row[1].size and float(np.abs(row[1]).max()) > self.max_quantity:
+                    self._rejected += 1
+                    return False
+            raw = (
+                tuple(
+                    (np.array(ii, np.int32), np.array(vv, np.float32))
+                    for ii, vv in delta.bundles
+                ),
+                np.asarray(delta.pi, np.float32),
+            )
+            self._pending[delta.key] = ("upsert", row, raw)
+            return True
 
     def withdraw(self, key) -> bool:
         """Queue a withdrawal.  Unknown keys are rejected (False)."""
-        self._wal_append(("withdraw", key))
-        pending = self._pending.get(key)
-        if pending is not None and pending[0] == "upsert" and key not in self.book:
-            # an unsettled submission cancels without ever touching the book
-            del self._pending[key]
+        with span("withdraw", epoch=self.epoch):
+            with span("wal_append"):
+                self._wal_append(("withdraw", key))
+            pending = self._pending.get(key)
+            if pending is not None and pending[0] == "upsert" and key not in self.book:
+                # an unsettled submission cancels without ever touching the book
+                del self._pending[key]
+                return True
+            if key not in self.book and pending is None:
+                self._rejected += 1
+                return False
+            self._pending[key] = ("remove",)
             return True
-        if key not in self.book and pending is None:
-            self._rejected += 1
-            return False
-        self._pending[key] = ("remove",)
-        return True
 
     def poll_prices(self) -> tuple[np.ndarray, int]:
         """Last-good settled price curve (reserve before any tick) + its epoch.
@@ -510,116 +516,122 @@ class MarketService:
         deltas stay queued for the next binding tick — and records nothing,
         mirroring ``Economy.preview_prices``'s side-effect-free contract.
         """
-        if deadline_s is None:
-            deadline_s = self.tick_deadline_s
-        if dry_run:
-            submitted = withdrawn = 0
-        else:
-            submitted, withdrawn = self._drain()
-            self._hook("post_drain")
-        problem = self.book.device_problem()
-
-        dropped = 0
-        if self.faults is not None and not self.faults.disabled:
-            # bid-stream dropout as a PURE mask overlay: the book is not
-            # mutated, so the incremental/full-repack parity is unaffected
-            # and the same epoch's dry run sees the identical draw (the
-            # fault stream is counter-based on the epoch index)
-            draw = self.faults.draw(
-                self.epoch, self.book.rows_cap, 1, self.book.num_resources
-            )
-            if draw.dropout is not None:
-                drop = np.asarray(draw.dropout, bool)
-                live = self.book.mask.any(axis=1)
-                dropped = int((drop & live).sum())
-                if dropped:
-                    problem = dataclasses.replace(
-                        problem,
-                        bundle_mask=problem.bundle_mask
-                        & ~jnp.asarray(drop)[:, None],
-                    )
-
-        warm = self.warm_start and bool(self.price_history)
-        start = (
-            np.maximum(self.price_history[-1], self.reserve)
-            if warm
-            else self.reserve
-        )
-        result, escalations, deadline_missed = self._settle(
-            problem, jnp.asarray(np.asarray(start, np.float32)), deadline_s
-        )
-        prices = np.asarray(result.prices)
-        converged = bool(result.converged)
-        sys_ok = all(verify_system(problem, result).values())
-        surplus, trade = surplus_and_trade(problem, result)
-
-        won = np.asarray(result.won)
-        chosen = np.maximum(np.asarray(result.chosen_bundle), 0)
-        pay = np.asarray(result.payments).astype(np.float64)
-        pi = np.take_along_axis(
-            np.asarray(problem.pi, np.float64), chosen[:, None], axis=1
-        )[:, 0]
-        g = won & (np.abs(pay) > 1e-9)
-        gammas = np.abs(pi[g] - pay[g]) / np.abs(pay[g])
-        base = np.asarray(self.book.base_cost, np.float64)
-        # operator rows are supply, not demand: they settle by construction
-        # whenever p >= reserve, so they belong in neither side of the
-        # "how many bids settled" ratio
-        is_op = self._operator_slot_mask()
-        agent_rows = self.book.num_rows - int(is_op.sum())
-        agent_won = int((won & ~is_op).sum())
-        self._hook("post_settle")
-
-        if not dry_run:
-            if converged:
-                self.health.on_success(self.epoch)
+        with span("tick", epoch=self.epoch, dry_run=dry_run):
+            if deadline_s is None:
+                deadline_s = self.tick_deadline_s
+            if dry_run:
+                submitted = withdrawn = 0
             else:
-                self.health.on_failure(self.backoff_base_s, self.backoff_cap_s)
+                with span("drain", rows=len(self._pending)):
+                    submitted, withdrawn = self._drain()
+                self._hook("post_drain")
+            problem = self.book.device_problem()
 
-        stats = EpochStats(
-            epoch=self.epoch,
-            prices=prices,
-            reserve=np.asarray(self.reserve),
-            psi=self._settled_psi(won, chosen),
-            price_ratio=prices / base,
-            gamma_median=float(np.median(gammas)) if gammas.size else float("nan"),
-            gamma_mean=float(np.mean(gammas)) if gammas.size else float("nan"),
-            pct_settled=100.0 * agent_won / max(agent_rows, 1),
-            buy_util_percentiles=np.empty(0),
-            sell_util_percentiles=np.empty(0),
-            migrations=0,
-            surplus=float(surplus),
-            value_of_trade=float(trade),
-            rounds=int(result.rounds),
-            converged=converged,
-            system_ok=sys_ok,
-            warm_started=warm,
-            degraded=bool(not converged or dropped or deadline_missed),
-            clock_escalations=escalations,
-            dropped_bids=dropped,
-            bids_submitted=submitted,
-            bids_withdrawn=withdrawn,
-            bids_rejected=self._rejected,
-            bids_deferred=self._deferred,
-            deadline_missed=deadline_missed,
-            tick_failures=self.health.consecutive_failures,
-            retry_backoff_s=self.health.retry_backoff_s,
-            health=self.health.state,
-        )
-        if not dry_run:
-            self._rejected = 0
-            self._deferred = 0
-            if converged:
-                self.price_history.append(prices)
-                self._last_price_epoch = self.epoch
-                self._prices_since_ckpt += 1
-                del self.price_history[: -self.max_history]
-            self.stats_history.append(stats)
-            self._stats_since_ckpt += 1
-            del self.stats_history[: -self.max_history]
-            self.epoch += 1
-            self._commit_durable()
-        return stats
+            dropped = 0
+            if self.faults is not None and not self.faults.disabled:
+                # bid-stream dropout as a PURE mask overlay: the book is not
+                # mutated, so the incremental/full-repack parity is unaffected
+                # and the same epoch's dry run sees the identical draw (the
+                # fault stream is counter-based on the epoch index)
+                draw = self.faults.draw(
+                    self.epoch, self.book.rows_cap, 1, self.book.num_resources
+                )
+                if draw.dropout is not None:
+                    drop = np.asarray(draw.dropout, bool)
+                    live = self.book.mask.any(axis=1)
+                    dropped = int((drop & live).sum())
+                    if dropped:
+                        problem = dataclasses.replace(
+                            problem,
+                            bundle_mask=problem.bundle_mask
+                            & ~jnp.asarray(drop)[:, None],
+                        )
+
+            warm = self.warm_start and bool(self.price_history)
+            start = (
+                np.maximum(self.price_history[-1], self.reserve)
+                if warm
+                else self.reserve
+            )
+            with span("clock"):
+                result, escalations, deadline_missed = self._settle(
+                    problem, jnp.asarray(np.asarray(start, np.float32)), deadline_s
+                )
+                prices = np.asarray(result.prices)
+                converged = bool(result.converged)
+            with span("verify"):
+                sys_ok = all(verify_system(problem, result).values())
+            with span("stats"):
+                surplus, trade = surplus_and_trade(problem, result)
+
+                won = np.asarray(result.won)
+                chosen = np.maximum(np.asarray(result.chosen_bundle), 0)
+                pay = np.asarray(result.payments).astype(np.float64)
+                pi = np.take_along_axis(
+                    np.asarray(problem.pi, np.float64), chosen[:, None], axis=1
+                )[:, 0]
+                g = won & (np.abs(pay) > 1e-9)
+                gammas = np.abs(pi[g] - pay[g]) / np.abs(pay[g])
+                base = np.asarray(self.book.base_cost, np.float64)
+                # operator rows are supply, not demand: they settle by construction
+                # whenever p >= reserve, so they belong in neither side of the
+                # "how many bids settled" ratio
+                is_op = self._operator_slot_mask()
+                agent_rows = self.book.num_rows - int(is_op.sum())
+                agent_won = int((won & ~is_op).sum())
+                self._hook("post_settle")
+
+                if not dry_run:
+                    if converged:
+                        self.health.on_success(self.epoch)
+                    else:
+                        self.health.on_failure(self.backoff_base_s, self.backoff_cap_s)
+
+                stats = EpochStats(
+                    epoch=self.epoch,
+                    prices=prices,
+                    reserve=np.asarray(self.reserve),
+                    psi=self._settled_psi(won, chosen),
+                    price_ratio=prices / base,
+                    gamma_median=float(np.median(gammas)) if gammas.size else float("nan"),
+                    gamma_mean=float(np.mean(gammas)) if gammas.size else float("nan"),
+                    pct_settled=100.0 * agent_won / max(agent_rows, 1),
+                    buy_util_percentiles=np.empty(0),
+                    sell_util_percentiles=np.empty(0),
+                    migrations=0,
+                    surplus=float(surplus),
+                    value_of_trade=float(trade),
+                    rounds=int(result.rounds),
+                    converged=converged,
+                    system_ok=sys_ok,
+                    warm_started=warm,
+                    degraded=bool(not converged or dropped or deadline_missed),
+                    clock_escalations=escalations,
+                    dropped_bids=dropped,
+                    bids_submitted=submitted,
+                    bids_withdrawn=withdrawn,
+                    bids_rejected=self._rejected,
+                    bids_deferred=self._deferred,
+                    deadline_missed=deadline_missed,
+                    tick_failures=self.health.consecutive_failures,
+                    retry_backoff_s=self.health.retry_backoff_s,
+                    health=self.health.state,
+                )
+            if not dry_run:
+                self._rejected = 0
+                self._deferred = 0
+                if converged:
+                    self.price_history.append(prices)
+                    self._last_price_epoch = self.epoch
+                    self._prices_since_ckpt += 1
+                    del self.price_history[: -self.max_history]
+                self.stats_history.append(stats)
+                self._stats_since_ckpt += 1
+                del self.stats_history[: -self.max_history]
+                self.epoch += 1
+                with span("commit"):
+                    self._commit_durable()
+            return stats
 
     def _settle_async_save(self) -> bool:
         """Resolve the previous tick's in-flight background save, if any.
@@ -647,7 +659,8 @@ class MarketService:
         a crash during the overlap window replays it."""
         if self._wal is None:
             return
-        removed = self._wal.truncate_to(self._durable_wal_offset)
+        with span("wal.truncate"):
+            removed = self._wal.truncate_to(self._durable_wal_offset)
         if removed:
             floor = self._wal.data_start
             self._wal_drained_offset = max(
@@ -656,6 +669,12 @@ class MarketService:
             self._durable_wal_offset = max(
                 self._durable_wal_offset - removed, floor
             )
+
+    def _sync_wal(self) -> None:
+        """Group commit of the WAL (a real fsync), if there is one."""
+        if self._wal is not None:
+            with span("wal.sync"):
+                self._wal.sync()
 
     def _commit_durable(self) -> None:
         """Tick-boundary durability: checkpoint, then compact the WAL.
@@ -677,14 +696,13 @@ class MarketService:
         ticks are power-durable even under the cheap per-append flush
         mode."""
         if self._ckpt is None:
-            if self._wal is not None:
-                self._wal.sync()
+            self._sync_wal()
             return
         self._hook("pre_commit_wait")
-        self._settle_async_save()
+        with span("commit.wait"):
+            self._settle_async_save()
         if self.epoch % self.checkpoint_interval != 0:
-            if self._wal is not None:
-                self._wal.sync()
+            self._sync_wal()
             return
         if self.async_commit:
             # truncate to the *previous* save's durable offset before
@@ -692,8 +710,7 @@ class MarketService:
             # until the next tick proves it durable
             self._truncate_wal()
             self._ckpt.save_async(self)
-            if self._wal is not None:
-                self._wal.sync()
+            self._sync_wal()
         else:
             self._ckpt.save(self, block=True)
             if self._wal is not None:
@@ -710,8 +727,7 @@ class MarketService:
         ok = True
         if self._ckpt is not None:
             ok = self._settle_async_save()
-        if self._wal is not None:
-            self._wal.sync()
+        self._sync_wal()
         return ok
 
     def checkpoint(self) -> int | None:
@@ -726,7 +742,7 @@ class MarketService:
         if self._wal is not None:
             self._durable_wal_offset = self._wal_drained_offset
             self._truncate_wal()
-            self._wal.sync()
+            self._sync_wal()
         return step
 
     def preview(self) -> EpochStats:
@@ -831,9 +847,15 @@ def main(argv=None):
     ap.add_argument("--kill-resume", action="store_true",
                     help="drop the service mid-horizon and resume from disk")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record the ticks' market.* spans and device ops "
+                         "with jax.profiler.trace(DIR)")
     args = ap.parse_args(argv)
 
+    import contextlib
     import os
+
+    import jax
 
     from .. import compile_cache
 
@@ -862,45 +884,47 @@ def main(argv=None):
     keys, idx_rows, val_rows, mask_rows, pi_rows = eco.export_bid_rows()
     live = np.flatnonzero(mask_rows.any(axis=1))
     withdrawn_keys: set = set()
-    for t in range(args.ticks):
-        n_delta = max(1, int(args.churn * args.agents))
-        pick = rng.choice(live, size=min(n_delta, live.size), replace=False)
-        scale = rng.uniform(0.9, 1.1, size=pick.size).astype(np.float32)
-        for j, i in enumerate(pick):
-            if keys[i] in withdrawn_keys:
-                withdrawn_keys.discard(keys[i])  # re-submission revives it
-            bundles = [
-                (idx_rows[i, b], val_rows[i, b])
-                for b in np.flatnonzero(mask_rows[i])
-            ]
-            pi = pi_rows[i][mask_rows[i]] * scale[j]
-            svc.submit(BidDelta(keys[i], bundles, pi))
-        n_wd = int(args.withdraw_frac * args.agents)
-        if n_wd:
-            for i in rng.choice(live, size=min(n_wd, live.size), replace=False):
-                if keys[i] not in withdrawn_keys and svc.withdraw(keys[i]):
-                    withdrawn_keys.add(keys[i])
-        if args.kill_resume and args.durable_dir and t == args.ticks // 2:
-            pend = svc.pending
-            del svc  # hard drop mid-horizon: no checkpoint, no drain
-            svc = MarketService.from_economy(eco, config=cfg, faults=faults)
+    profile = jax.profiler.trace(args.profile) if args.profile else contextlib.nullcontext()
+    with profile:
+        for t in range(args.ticks):
+            n_delta = max(1, int(args.churn * args.agents))
+            pick = rng.choice(live, size=min(n_delta, live.size), replace=False)
+            scale = rng.uniform(0.9, 1.1, size=pick.size).astype(np.float32)
+            for j, i in enumerate(pick):
+                if keys[i] in withdrawn_keys:
+                    withdrawn_keys.discard(keys[i])  # re-submission revives it
+                bundles = [
+                    (idx_rows[i, b], val_rows[i, b])
+                    for b in np.flatnonzero(mask_rows[i])
+                ]
+                pi = pi_rows[i][mask_rows[i]] * scale[j]
+                svc.submit(BidDelta(keys[i], bundles, pi))
+            n_wd = int(args.withdraw_frac * args.agents)
+            if n_wd:
+                for i in rng.choice(live, size=min(n_wd, live.size), replace=False):
+                    if keys[i] not in withdrawn_keys and svc.withdraw(keys[i]):
+                        withdrawn_keys.add(keys[i])
+            if args.kill_resume and args.durable_dir and t == args.ticks // 2:
+                pend = svc.pending
+                del svc  # hard drop mid-horizon: no checkpoint, no drain
+                svc = MarketService.from_economy(eco, config=cfg, faults=faults)
+                print(
+                    f"[market] killed + resumed: epoch {svc.epoch}, "
+                    f"{svc.replayed_records} WAL records replayed, "
+                    f"{svc.pending}/{pend} pending reconstructed",
+                    flush=True,
+                )
+            t0 = time.time()
+            s = svc.tick()
+            dt = time.time() - t0
             print(
-                f"[market] killed + resumed: epoch {svc.epoch}, "
-                f"{svc.replayed_records} WAL records replayed, "
-                f"{svc.pending}/{pend} pending reconstructed",
+                f"[market] tick {t}: {s.bids_submitted} bids in, "
+                f"{s.bids_withdrawn} out, {s.dropped_bids} dropped, "
+                f"{s.rounds} rounds, converged={s.converged}, "
+                f"health={s.health}, pct_settled={s.pct_settled:.1f}%, "
+                f"peak psi={s.psi.max():.2f}, {dt*1e3:.0f} ms",
                 flush=True,
             )
-        t0 = time.time()
-        s = svc.tick()
-        dt = time.time() - t0
-        print(
-            f"[market] tick {t}: {s.bids_submitted} bids in, "
-            f"{s.bids_withdrawn} out, {s.dropped_bids} dropped, "
-            f"{s.rounds} rounds, converged={s.converged}, "
-            f"health={s.health}, pct_settled={s.pct_settled:.1f}%, "
-            f"peak psi={s.psi.max():.2f}, {dt*1e3:.0f} ms",
-            flush=True,
-        )
     svc.book.parity_check()
     print("[market] incremental book bit-identical to full repack", flush=True)
     return 0

@@ -38,8 +38,7 @@ Durability modes (``sync=``):
   death (``os._exit``, SIGKILL, the failure model the recovery suite
   exercises); it is lost only on kernel panic or power failure.
 * ``"fsync"`` — additionally ``os.fsync`` per append: power-failure
-  durable, at ~5× the per-submit cost (measured in the
-  ``market_recover`` benchmark).
+  durable, at ~5× the per-submit cost on a CPU host.
 * ``"none"`` — buffered writes, flushed only on :meth:`sync`/close.
 
 Whatever the mode, the service calls :meth:`sync` (a real fsync) at every
