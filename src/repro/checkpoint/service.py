@@ -81,6 +81,12 @@ class _Payload:
     prev_deltas_since_full: int
     prev_base_step: int | None
 
+    def span_stats(self) -> dict:
+        """The snapshot span's stats: live accounts encoded, and how many
+        of them took the raw (bundles, pi) path."""
+        kinds = self.tree["book/kinds"]
+        return {"accounts": int(kinds.size), "raw": int(np.count_nonzero(kinds == 0))}
+
 
 class ServiceCheckpointer(CheckpointStore):
     """Persist/restore full mutable MarketService state at tick boundaries."""
@@ -291,8 +297,9 @@ class ServiceCheckpointer(CheckpointStore):
             return self.save_async(svc, force_full=force_full)
         full = self._next_is_full(svc, force_full)
         with span("checkpoint.full" if full else "checkpoint.delta", step=int(svc.epoch)):
-            with span("checkpoint.snapshot"):
+            with span("checkpoint.snapshot") as snap:
                 payload = self._snapshot(svc, full)
+                snap.set_metadata(**payload.span_stats())
             try:
                 self._write_payload(payload)
             except BaseException:
@@ -314,8 +321,9 @@ class ServiceCheckpointer(CheckpointStore):
         # the write runs on the writer thread, under its own
         # ``market.checkpoint.write`` span
         with span("checkpoint.full" if full else "checkpoint.delta", step=int(svc.epoch)):
-            with span("checkpoint.snapshot"):
+            with span("checkpoint.snapshot") as snap:
                 payload = self._snapshot(svc, full, copy=True)
+                snap.set_metadata(**payload.span_stats())
         self._inflight = payload
 
         def work():

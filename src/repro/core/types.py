@@ -941,6 +941,7 @@ class MarketBook:
         self._key_slot: dict = {}
         self._slot_key: list = [None] * self.rows_cap
         self._accounts: dict = {}  # key -> (bundles tuple, pi tuple) as packed
+        self._raw_queue: dict = {}  # slot -> raw account its columns await
         self._next_slot = 0
         self._free: list[int] = []  # LIFO of freed slots below _next_slot
         self._ledger = np.zeros(self.num_resources, np.float64)
@@ -966,6 +967,35 @@ class MarketBook:
         self.val = np.zeros(rows_cap * b * k, np.float32)
         self.mask = np.zeros((rows_cap, b), bool)
         self.pi = np.zeros((rows_cap, b), np.float32)
+        # the account mirror: each slot's submission in columns the
+        # checkpoint encoder gathers from.  ``kind`` is -1 for an empty slot,
+        # 0 for a raw (bundles, pi) submission (``count`` bundles, ``nnz``
+        # elements each, in submitted order; ``pi`` broadcast to the
+        # bundles), 1 for a pre-packed (idx, val, mask, pi) payload.  It is
+        # written from the submissions, never from the slot arrays above, so
+        # parity_check on a restored book stays an independent oracle.
+        self._acct = {
+            "kind": np.full(rows_cap, -1, np.int8),
+            "count": np.zeros(rows_cap, np.int32),
+            "nnz": np.zeros((rows_cap, b), np.int32),
+            "idx": np.zeros((rows_cap, b, k), np.int32),
+            "val": np.zeros((rows_cap, b, k), np.float32),
+            "pi": np.zeros((rows_cap, b), np.float32),
+            "mask": np.zeros((rows_cap, b), bool),
+        }
+
+    def _slot_arrays(self) -> tuple[np.ndarray, ...]:
+        return (self.idx, self.val, self.mask, self.pi, *self._acct.values())
+
+    def _grow(self, new_cap: int) -> None:
+        """Reallocate every per-slot array at ``new_cap`` slots, keeping
+        the first ``rows_cap`` slots' contents."""
+        old = self._slot_arrays()
+        self._alloc_arrays(new_cap)
+        for new, a in zip(self._slot_arrays(), old):
+            new[: a.shape[0]] = a
+        self._slot_key.extend([None] * (new_cap - self.rows_cap))
+        self.rows_cap = new_cap
 
     def _ensure_rows(self, extra: int) -> None:
         need = self._next_slot - len(self._free) + extra
@@ -974,15 +1004,7 @@ class MarketBook:
         new_cap = self.rows_cap
         while new_cap < need:
             new_cap *= 2
-        b, k = self.num_bundles, self.k_bound
-        idx, val, mask, pi = self.idx, self.val, self.mask, self.pi
-        self._alloc_arrays(new_cap)
-        self.idx[: idx.shape[0]] = idx
-        self.val[: val.shape[0]] = val
-        self.mask[: mask.shape[0]] = mask
-        self.pi[: pi.shape[0]] = pi
-        self._slot_key.extend([None] * (new_cap - self.rows_cap))
-        self.rows_cap = new_cap
+        self._grow(new_cap)
         self._generation += 1  # stale device mirror: full re-upload
         self._dev = None
         self._dev_pending.clear()
@@ -1039,28 +1061,99 @@ class MarketBook:
     def upsert(self, key, bundles, pi) -> None:
         """Insert or replace one account's bid.  Amortized O(B·K)."""
         row = self._pack_row(bundles, pi)
-        self._write_rows([key], *(a[None] for a in row))
-        self._accounts[key] = (tuple(
+        slots = self._write_rows([key], *(a[None] for a in row))
+        acct = (tuple(
             (np.array(ii, np.int32), np.array(vv, np.float32)) for ii, vv in bundles
         ), np.asarray(pi, np.float32))
+        self._accounts[key] = acct
+        self._mirror_raw(slots, [acct])
 
     def upsert_rows(self, keys, idx_rows, val_rows, mask_rows, pi_rows, raw=None):
         """Vectorized multi-account upsert of pre-packed row payloads.
 
-        ``raw`` optionally carries the original (bundles, pi) submissions so
-        :meth:`rebuilt` can re-pack them; when omitted the payload itself is
-        stored (already canonical)."""
-        self._write_rows(keys, idx_rows, val_rows, mask_rows, pi_rows)
+        ``raw`` optionally carries the original (bundles, pi) submissions,
+        each one :meth:`_pack_row` accepted, so :meth:`rebuilt` can re-pack
+        them; when omitted the payload itself is stored (already
+        canonical)."""
+        slots = self._write_rows(keys, idx_rows, val_rows, mask_rows, pi_rows)
+        if raw is not None:
+            self._accounts.update(zip(keys, raw))
+            self._mirror_raw(slots, raw)
+            return
         for i, key in enumerate(keys):
-            if raw is not None:
-                self._accounts[key] = raw[i]
-            else:
-                self._accounts[key] = (
-                    idx_rows[i].copy(), val_rows[i].copy(),
-                    mask_rows[i].copy(), pi_rows[i].copy(),
-                )
+            self._accounts[key] = (
+                idx_rows[i].copy(), val_rows[i].copy(),
+                mask_rows[i].copy(), pi_rows[i].copy(),
+            )
+        self._mirror_packed(slots, idx_rows, val_rows, mask_rows, pi_rows)
 
-    def _write_rows(self, keys, idx_rows, val_rows, mask_rows, pi_rows) -> None:
+    def _mirror_raw(self, slots, accts) -> None:
+        """Mirror raw (bundles, pi) submissions into ``slots``: the kind at
+        once, the columns at the next export (:meth:`_flush_raw`).  A drain
+        pays one dict update; each account's bundles are flattened once, in
+        the checkpoint snapshot that follows."""
+        slots = np.asarray(slots, np.int64)
+        self._acct["kind"][slots] = 0
+        self._raw_queue.update(zip(slots.tolist(), accts))
+
+    def _flush_raw(self) -> None:
+        """Write the queued raw submissions into the mirror's columns: one
+        concatenate of their bundles and one scatter per column."""
+        if not self._raw_queue:
+            return
+        b, k, m = self.num_bundles, self.k_bound, self._acct
+        slots = np.fromiter(self._raw_queue, np.int64, len(self._raw_queue))
+        accts = list(self._raw_queue.values())
+        bundles = [q for acct in accts for q in acct[0]]
+        counts = np.fromiter((len(acct[0]) for acct in accts), np.int64, len(accts))
+        nnz = np.fromiter((len(ii) for ii, _ in bundles), np.int64, len(bundles))
+        pi_len = np.fromiter((np.size(acct[1]) for acct in accts), np.int64, len(accts))
+        if counts.max() > b or nnz.max() > k or not np.all((pi_len == 1) | (pi_len == counts)):
+            raise ValueError("raw submission does not fit the book (pack it first)")
+        self._raw_queue.clear()
+        # bundle j of account a lands in row slots[a]·B + j, its elements in
+        # that row's first nnz columns; a scalar pi serves every bundle
+        j = np.arange(len(bundles)) - np.repeat(np.cumsum(counts) - counts, counts)
+        rows = np.repeat(slots, counts) * b + j
+        el = np.repeat(rows * k, nnz) + np.arange(int(nnz.sum())) - np.repeat(
+            np.cumsum(nnz) - nnz, nnz
+        )
+        pi_at = np.repeat(np.cumsum(pi_len) - pi_len, counts) + np.where(
+            np.repeat(pi_len, counts) == 1, 0, j
+        )
+        pis = np.concatenate([acct[1] for acct in accts], axis=None)
+        m["count"][slots] = counts
+        m["nnz"][slots] = 0
+        m["nnz"].reshape(-1)[rows] = nnz
+        m["idx"].reshape(-1)[el] = np.concatenate([ii for ii, _ in bundles])
+        m["val"].reshape(-1)[el] = np.concatenate([vv for _, vv in bundles])
+        m["pi"].reshape(-1)[rows] = pis.astype(np.float32)[pi_at]
+
+    def _mirror_packed(self, slots, idx_rows, val_rows, mask_rows, pi_rows) -> None:
+        """Mirror pre-packed row payloads into ``slots``."""
+        self._flush_raw()  # a queued raw write must not land over these
+        b, k, m = self.num_bundles, self.k_bound, self._acct
+        slots = np.asarray(slots, np.int64)
+        m["kind"][slots] = 1
+        m["idx"][slots] = np.asarray(idx_rows, np.int32).reshape(-1, b, k)
+        m["val"][slots] = np.asarray(val_rows, np.float32).reshape(-1, b, k)
+        m["mask"][slots] = np.asarray(mask_rows, bool)
+        m["pi"][slots] = np.asarray(pi_rows, np.float32)
+
+    def _mirror_accounts(self, pairs) -> None:
+        """Mirror decoded ``(slot, account)`` pairs of either kind."""
+        packed = [(s, a) for s, a in pairs if len(a) != 2]
+        raw = [(s, a) for s, a in pairs if len(a) == 2]
+        if packed:
+            self._mirror_packed(
+                [s for s, _ in packed],
+                *(np.stack([a[j] for _, a in packed]) for j in range(4)),
+            )
+        self._mirror_raw([s for s, _ in raw], [a for _, a in raw])
+
+    def _write_rows(self, keys, idx_rows, val_rows, mask_rows, pi_rows) -> np.ndarray:
+        """Write pre-packed rows into the keys' slots (allocating new keys'
+        slots); returns the slots."""
         d = len(keys)
         if len(set(keys)) != d:
             # the ledger reads each slot's old contents once per batch, so a
@@ -1119,6 +1212,7 @@ class MarketBook:
         self._dev_pending.extend(int(s) for s in slots)
         self._ckpt_dirty.update(int(s) for s in slots)
         self.deltas_applied += d
+        return slots
 
     def remove(self, key) -> bool:
         """Withdraw one account's bid; frees its slot (LIFO reuse).  O(B·K)."""
@@ -1143,6 +1237,8 @@ class MarketBook:
         self.pi[s] = 0.0
         self._slot_key[s] = None
         self._accounts.pop(key, None)
+        self._acct["kind"][s] = -1
+        self._raw_queue.pop(s, None)
         self._free.append(s)
         self._dev_pending.append(s)
         self._ckpt_dirty.add(int(s))
@@ -1285,6 +1381,8 @@ class MarketBook:
             )
         fresh._next_slot = self._next_slot
         fresh._free = [s for s in range(self._next_slot) if self._slot_key[s] is None]
+        self._flush_raw()
+        fresh._acct = {name: a.copy() for name, a in self._acct.items()}
         return fresh
 
     def parity_check(self) -> None:
@@ -1314,83 +1412,47 @@ class MarketBook:
         return self._sell_ledger.copy()
 
     def _encode_accounts(
-        self, live_slots: Sequence[int]
+        self, live: np.ndarray
     ) -> tuple[list, dict[str, np.ndarray]]:
-        """CSR-flatten the raw accounts behind ``live_slots`` (ascending
-        slot order, every slot live) into O(1) npz-able arrays.  Shared by
-        the full and dirty-row exporters so both spell the identical
-        on-disk encoding."""
-        keys: list = []
-        slots: list[int] = []
-        kinds: list[int] = []  # 0 = raw (bundles, pi), 1 = pre-packed payload
-        raw_counts: list[int] = []
-        raw_nnz: list[int] = []
-        raw_idx: list[np.ndarray] = []
-        raw_val: list[np.ndarray] = []
-        raw_pi: list[np.ndarray] = []
-        packed_idx: list[np.ndarray] = []
-        packed_val: list[np.ndarray] = []
-        packed_mask: list[np.ndarray] = []
-        packed_pi: list[np.ndarray] = []
-        b_cap, k_cap = self.num_bundles, self.k_bound
-        for s in live_slots:
-            key = self._slot_key[s]
-            try:
-                json.dumps(key)
-            except TypeError:
-                raise TypeError(
-                    f"book key {key!r} is not JSON-serializable — durable "
-                    "books require str/int keys"
-                ) from None
-            acct = self._accounts[key]
-            keys.append(key)
-            slots.append(s)
-            if len(acct) == 2:  # raw (bundles, pi) submission
-                bundles, pi = acct
-                kinds.append(0)
-                raw_counts.append(len(bundles))
-                pi_arr = np.broadcast_to(
-                    np.asarray(pi, np.float32), (len(bundles),)
-                )
-                raw_pi.append(np.asarray(pi_arr, np.float32))
-                for ii, vv in bundles:
-                    ii = np.asarray(ii, np.int32).reshape(-1)
-                    raw_nnz.append(ii.shape[0])
-                    raw_idx.append(ii)
-                    raw_val.append(np.asarray(vv, np.float32).reshape(-1))
-            else:  # pre-packed (idx, val, mask, pi) payload
-                kinds.append(1)
-                packed_idx.append(np.asarray(acct[0], np.int32))
-                packed_val.append(np.asarray(acct[1], np.float32))
-                packed_mask.append(np.asarray(acct[2], bool))
-                packed_pi.append(np.asarray(acct[3], np.float32))
-
-        def _cat(chunks, dtype):
-            return (
-                np.concatenate(chunks).astype(dtype, copy=False)
-                if chunks
-                else np.zeros(0, dtype)
-            )
-
-        def _stack(chunks, dtype, shape):
-            return (
-                np.stack(chunks).astype(dtype, copy=False)
-                if chunks
-                else np.zeros((0, *shape), dtype)
-            )
-
+        """CSR-flatten the raw accounts behind ``live`` (ascending slot
+        order, every slot live) into O(1) npz-able arrays: gathers over the
+        account mirror, slots → bundles < count → elements < nnz in C order.
+        Shared by the full and dirty-row exporters so both spell the
+        identical on-disk encoding."""
+        self._flush_raw()
+        live = np.asarray(live, np.int64)
+        keys = [self._slot_key[s] for s in live.tolist()]
+        try:
+            json.dumps(keys)
+        except TypeError:
+            for key in keys:  # name the first key that is not JSON-able
+                try:
+                    json.dumps(key)
+                except TypeError:
+                    raise TypeError(
+                        f"book key {key!r} is not JSON-serializable — durable "
+                        "books require str/int keys"
+                    ) from None
+            raise
+        m = self._acct
+        kinds = m["kind"][live]
+        raw, packed = live[kinds == 0], live[kinds == 1]
+        counts = m["count"][raw]
+        nnz = m["nnz"][raw]  # 0 past each account's bundle count
+        bundles = np.arange(self.num_bundles) < counts[:, None]
+        elements = np.arange(self.k_bound) < nnz[..., None]
         return keys, {
-            "slots": np.asarray(slots, np.int64),
-            "kinds": np.asarray(kinds, np.int8),
-            "raw_counts": np.asarray(raw_counts, np.int32),
-            "raw_nnz": np.asarray(raw_nnz, np.int32),
-            "raw_idx": _cat(raw_idx, np.int32),
-            "raw_val": _cat(raw_val, np.float32),
-            "raw_pi": _cat(raw_pi, np.float32),
-            "packed_idx": _stack(packed_idx, np.int32, (b_cap, k_cap)),
-            "packed_val": _stack(packed_val, np.float32, (b_cap, k_cap)),
-            "packed_mask": _stack(packed_mask, bool, (b_cap,)),
-            "packed_pi": _stack(packed_pi, np.float32, (b_cap,)),
+            "slots": live,
+            "kinds": kinds,
+            "raw_counts": counts,
+            "raw_nnz": nnz[bundles],
+            "raw_idx": m["idx"][raw][elements],
+            "raw_val": m["val"][raw][elements],
+            "raw_pi": m["pi"][raw][bundles],
+            "packed_idx": m["idx"][packed],
+            "packed_val": m["val"][packed],
+            "packed_mask": m["mask"][packed],
+            "packed_pi": m["pi"][packed],
         }
 
     @staticmethod
@@ -1454,9 +1516,7 @@ class MarketBook:
         delta chains from.  The returned arrays alias live book storage —
         callers persisting them asynchronously must copy first.
         """
-        live = [
-            s for s in range(self._next_slot) if self._slot_key[s] is not None
-        ]
+        live = np.flatnonzero(self._acct["kind"][: self._next_slot] >= 0)
         keys, acct_arrays = self._encode_accounts(live)
         arrays = {
             "idx": self.idx,
@@ -1514,8 +1574,7 @@ class MarketBook:
         el = (
             sl[:, None] * (b * k) + np.arange(b * k, dtype=np.int64)[None, :]
         ).reshape(-1)
-        live = [s for s in rows if self._slot_key[s] is not None]
-        keys, acct_arrays = self._encode_accounts(live)
+        keys, acct_arrays = self._encode_accounts(sl[self._acct["kind"][sl] >= 0])
         arrays = {
             "rows": sl,
             "idx": self.idx[el],
@@ -1562,14 +1621,7 @@ class MarketBook:
         if new_cap < self.rows_cap:
             raise ValueError("delta record predates this book (rows_cap shrank)")
         if new_cap > self.rows_cap:
-            idx, val, mask, pi = self.idx, self.val, self.mask, self.pi
-            self._alloc_arrays(new_cap)
-            self.idx[: idx.shape[0]] = idx
-            self.val[: val.shape[0]] = val
-            self.mask[: mask.shape[0]] = mask
-            self.pi[: pi.shape[0]] = pi
-            self._slot_key.extend([None] * (new_cap - self.rows_cap))
-            self.rows_cap = new_cap
+            self._grow(new_cap)
         rows = np.asarray(arrays["rows"], np.int64)
         b, k = self.num_bundles, self.k_bound
         el = (
@@ -1589,8 +1641,12 @@ class MarketBook:
             if key is not None:
                 self._slot_key[int(s)] = key
                 self._key_slot[key] = int(s)
-        for key, _s, acct in self._decode_accounts(arrays, meta["keys"]):
+        self._acct["kind"][rows] = -1
+        pairs = []
+        for key, s, acct in self._decode_accounts(arrays, meta["keys"]):
             self._accounts[key] = acct
+            pairs.append((s, acct))
+        self._mirror_accounts(pairs)
         self._ledger = np.asarray(arrays["ledger"], np.float64).copy()
         self._sell_ledger = np.asarray(arrays["sell_ledger"], np.float64).copy()
         self._free = [int(x) for x in arrays["free"]]
@@ -1633,10 +1689,13 @@ class MarketBook:
         book._next_slot = int(meta["next_slot"])
         book._generation = int(meta["generation"])
         book.deltas_applied = int(meta["deltas_applied"])
+        pairs = []
         for key, s, acct in cls._decode_accounts(arrays, meta["keys"]):
             book._key_slot[key] = s
             book._slot_key[s] = key
             book._accounts[key] = acct
+            pairs.append((s, acct))
+        book._mirror_accounts(pairs)
         return book
 
 

@@ -7,7 +7,9 @@ innermost span open over it.  Spans have no switch of their own: they
 record while the profiler collects (``jax.profiler.trace(dir)``); otherwise
 ``span`` returns a shared no-op context and costs under a microsecond of
 host time.  The ``stats`` are values the caller already holds (ints, a kind
-string); they ride on the trace event.
+string); they ride on the trace event.  Stats known only once the work is
+done go through the entered span's ``set_metadata(**stats)``, which the
+no-op context takes and drops.
 
 ``compiles()`` lists every backend compile of this process since the module
 was imported, as ``(perf_counter_end, fun_name, seconds)``: one
@@ -26,8 +28,20 @@ PREFIX = "market."
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 _compiles: list[tuple[float, str, float]] = []
-_OFF = contextlib.nullcontext()
 _collecting = jax.profiler.TraceAnnotation.is_enabled
+
+
+class _Off(contextlib.nullcontext):
+    """A span while the profiler is off: a shared no-op context."""
+
+    def __enter__(self):
+        return self
+
+    def set_metadata(self, **stats) -> None:
+        pass
+
+
+_OFF = _Off()
 
 
 def span(name: str, **stats):

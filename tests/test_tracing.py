@@ -184,3 +184,26 @@ def test_the_service_entry_point_profiles_its_ticks(tmp_path, capsys, monkeypatc
     ticks = [s for s in _spans(tmp_path / "trace") if s[0] == "market.tick"]
     assert [t[4]["epoch"] for t in ticks] == [0, 1]
     assert "tick 1:" in capsys.readouterr().out
+
+
+def test_the_snapshot_span_counts_the_accounts_it_encoded(tmp_path, monkeypatch):
+    eco, svc = _service(str(tmp_path / "svc"))
+    svc.tick()
+    book, expected = svc.book, []
+    for name, full in (("export_state", True), ("export_dirty_state", False)):
+        def counted(*args, _export=getattr(book, name), _full=full, **kwargs):
+            # the oracle: the book's slot keys and raw accounts, not its mirror
+            slots = range(book.rows_cap) if _full else sorted(book._ckpt_dirty)
+            keys = [book._slot_key[s] for s in slots if book._slot_key[s] is not None]
+            expected.append({"accounts": len(keys),
+                             "raw": sum(len(book._accounts[k]) == 2 for k in keys)})
+            return _export(*args, **kwargs)
+        monkeypatch.setattr(book, name, counted)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        _drive(eco, svc)
+    snaps = [x[4] for x in _spans(tmp_path / "trace") if x[0] == "market.checkpoint.snapshot"]
+    assert [{k: s[k] for k in ("accounts", "raw")} for s in snaps] == expected
+    # two deltas of re-prices alone, then a full over packed and raw accounts
+    assert len(expected) == 3 and all(0 < e["raw"] == e["accounts"] for e in expected[:2])
+    assert expected[2]["accounts"] == book.num_rows
+    assert 0 < expected[2]["raw"] < expected[2]["accounts"]
