@@ -68,6 +68,23 @@ def bundle_costs(bundles: jax.Array, mask: jax.Array, prices: jax.Array) -> jax.
     return jnp.where(mask, costs, jnp.inf)
 
 
+def _take_bundle(x: jax.Array, b: jax.Array) -> jax.Array:
+    """``x[u, b[u], ...]`` for an int (U,) ``b`` in ``[0, B)``.
+
+    A chain of B selects over the static bundle slices, bit-identical to
+    ``take_along_axis`` along axis 1 (a select does no arithmetic).  On the
+    TPU the gather form compiles to a gather fusion; the selects are
+    elementwise over lane-dense (U,) slices.  A compiled program around the
+    pick may still fuse, and so associate its sums, differently than around
+    the gather.
+    """
+    sel = b.reshape(b.shape + (1,) * (x.ndim - 2))
+    out = x[:, 0]
+    for j in range(1, x.shape[1]):
+        out = jnp.where(sel == j, x[:, j], out)
+    return out
+
+
 def proxy_demand(
     bundles: jax.Array, mask: jax.Array, pi: jax.Array, prices: jax.Array
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -79,14 +96,14 @@ def proxy_demand(
     costs = bundle_costs(bundles, mask, prices)  # (U, B)
     if pi.ndim == 1:
         bhat = jnp.argmin(costs, axis=1)  # cheapest alternative
-        cost_hat = jnp.take_along_axis(costs, bhat[:, None], axis=1)[:, 0]
+        cost_hat = _take_bundle(costs, bhat)
         active = cost_hat <= pi  # affordable?  (also correct for sellers)
     else:
         surplus = jnp.where(mask, pi - costs, -jnp.inf)  # (U, B)
         bhat = jnp.argmax(surplus, axis=1)
-        s_hat = jnp.take_along_axis(surplus, bhat[:, None], axis=1)[:, 0]
+        s_hat = _take_bundle(surplus, bhat)
         active = s_hat >= 0.0
-    x = jnp.take_along_axis(bundles, bhat[:, None, None], axis=1)[:, 0, :]
+    x = _take_bundle(bundles, bhat)
     x = x * active[:, None].astype(x.dtype)
     chosen = jnp.where(active, bhat, -1)
     return x, chosen, active
@@ -118,15 +135,15 @@ def _sparse_selection(
     costs = sparse_bundle_costs(idx, val, mask, prices)  # (U, B)
     if pi.ndim == 1:
         bhat = jnp.argmin(costs, axis=1)
-        cost_hat = jnp.take_along_axis(costs, bhat[:, None], axis=1)[:, 0]
+        cost_hat = _take_bundle(costs, bhat)
         active = cost_hat <= pi
     else:
         surplus = jnp.where(mask, pi - costs, -jnp.inf)
         bhat = jnp.argmax(surplus, axis=1)
-        s_hat = jnp.take_along_axis(surplus, bhat[:, None], axis=1)[:, 0]
+        s_hat = _take_bundle(surplus, bhat)
         active = s_hat >= 0.0
-    sel_idx = jnp.take_along_axis(idx, bhat[:, None, None], axis=1)[:, 0, :]
-    sel_val = jnp.take_along_axis(val, bhat[:, None, None], axis=1)[:, 0, :]
+    sel_idx = _take_bundle(idx, bhat)
+    sel_val = _take_bundle(val, bhat)
     sel_val = sel_val.astype(jnp.float32) * active[:, None]
     chosen = jnp.where(active, bhat, -1)
     return sel_idx, sel_val, chosen, active
@@ -176,8 +193,8 @@ def _user_rows(sel_idx: jax.Array, sel_val: jax.Array, num_resources: int) -> ja
         x = jnp.zeros((num_users, num_resources), jnp.float32)
         for kk in range(k):
             x = x + jnp.where(
-                r_iota == sel_idx[:, kk, None],
-                sel_val[:, kk, None].astype(jnp.float32),
+                r_iota == sel_idx[:, kk : kk + 1],
+                sel_val[:, kk : kk + 1].astype(jnp.float32),
                 0.0,
             )
         return x
@@ -369,12 +386,12 @@ def csr_proxy_demand(
 
     if pi.ndim == 1:
         bhat = jnp.argmin(costs, axis=1)
-        cost_hat = jnp.take_along_axis(costs, bhat[:, None], axis=1)[:, 0]
+        cost_hat = _take_bundle(costs, bhat)
         active = cost_hat <= pi
     else:
         surplus = jnp.where(mask, pi - costs, -jnp.inf)
         bhat = jnp.argmax(surplus, axis=1)
-        s_hat = jnp.take_along_axis(surplus, bhat[:, None], axis=1)[:, 0]
+        s_hat = _take_bundle(surplus, bhat)
         active = s_hat >= 0.0
     chosen = jnp.where(active, bhat, -1)
 
@@ -415,8 +432,8 @@ def _csr_settle(
         num_users, num_bundles
     )
     bsel = jnp.maximum(chosen, 0)
-    start_u = jnp.take_along_axis(starts, bsel[:, None], axis=1)[:, 0]
-    count_u = jnp.take_along_axis(counts, bsel[:, None], axis=1)[:, 0]
+    start_u = _take_bundle(starts, bsel)
+    count_u = _take_bundle(counts, bsel)
     kk = jnp.arange(k, dtype=start_u.dtype)
     live = kk[None, :] < count_u[:, None]
     if problem.nnz == 0:
@@ -630,8 +647,8 @@ def _sparse_settle(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Award bundles and compute payments — per-user, so shard-invariant."""
     bsel = jnp.maximum(chosen, 0)
-    alloc_idx = jnp.take_along_axis(idx, bsel[:, None, None], axis=1)[:, 0, :]
-    alloc_val = jnp.take_along_axis(val, bsel[:, None, None], axis=1)[:, 0, :]
+    alloc_idx = _take_bundle(idx, bsel)
+    alloc_val = _take_bundle(val, bsel)
     alloc_val = alloc_val.astype(jnp.float32) * active[:, None]
     if exact:
         # Rebuild the *chosen* bundle's dense (U, R) row and pay through the
@@ -1081,9 +1098,7 @@ def _verify_system_checks(
         # and nonneg surplus; losers must have no bundle with positive surplus.
         surplus = jnp.where(mask, pi - costs, -jnp.inf)
         best = jnp.max(surplus, axis=1)
-        won_sur = jnp.take_along_axis(
-            surplus, jnp.maximum(result.chosen_bundle, 0)[:, None], axis=1
-        )[:, 0]
+        won_sur = _take_bundle(surplus, jnp.maximum(result.chosen_bundle, 0))
         checks = {
             "c1_bundle_integrality": jnp.all(
                 jnp.where(won, result.chosen_bundle >= 0, True)

@@ -374,6 +374,18 @@ def csr_padded_views(problem: CSRAuctionProblem) -> tuple[jax.Array, jax.Array]:
             jnp.zeros((u, b, k), jnp.int32),
             jnp.zeros((u, b, k), jnp.float32),
         )
+    if problem.nnz == u * b * k:
+        # offsets run from 0 to nnz and no bundle holds more than k_bound
+        # entries, so every bundle holds exactly k_bound (the always-on
+        # book's fixed layout): offsets are arange·k_bound and the views are
+        # the flat streams reshaped, with no gather.  The (B·K, U) planes
+        # behind the barrier keep U on the TPU's lanes; a bare reshape lets
+        # the compiler put K on them, and every bundle slice then pays a
+        # relayout.
+        planes = jax.lax.optimization_barrier(
+            (problem.idx.reshape(u, b * k).T, problem.val.reshape(u, b * k).T)
+        )
+        return tuple(p.T.reshape(u, b, k) for p in planes)
     # The TPU compiler's time for this gather swings with the book's shape,
     # up to minutes and 100 MB of code at some row counts that are not a
     # multiple of 128.  Rows are padded to whole 128-row tiles (as empty

@@ -8,13 +8,18 @@ pool indices within one bundle, and the Pallas kernel under interpret=True.
 import dataclasses
 
 import numpy as np
+import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
+from jax import lax
 
 from repro.core import (
     ClockConfig,
     SparseAuctionProblem,
     clock_auction,
+    csr_padded_views,
+    csr_problem_from_arrays,
     densify,
     pack_bids,
     pack_bids_sparse,
@@ -24,7 +29,8 @@ from repro.core import (
     surplus_and_trade,
     verify_system,
 )
-from repro.core.auction import sparse_proxy_demand_exact
+from repro.core import auction
+from repro.core.auction import blocked_demand_fn, sparse_proxy_demand_exact
 from repro.kernels import ops, ref
 from repro.kernels.sparse_bid_eval import sparse_bid_eval as pallas_sparse_bid_eval
 
@@ -341,3 +347,199 @@ def test_clock_auction_rejects_mismatched_demand_fn():
         clock_auction(sp, p0, ClockConfig(), demand_fn=proxy_demand)
     with pytest.raises(TypeError):
         clock_auction(prob, p0, ClockConfig(), demand_fn=sparse_proxy_demand)
+
+
+# ---------------------------------------------------------------------------
+# bundle pick: exact selects in place of the bundle-axis gather
+# ---------------------------------------------------------------------------
+
+
+def _gather_bundle(x, b):
+    """The oracle: ``take_along_axis`` along the bundle axis."""
+    ix = b.reshape(b.shape + (1,) * (x.ndim - 1))
+    return jnp.take_along_axis(x, ix, axis=1)[:, 0]
+
+
+def _bits(x):
+    x = jnp.asarray(x)
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = lax.bitcast_convert_type(x, jnp.int32)
+    return np.asarray(x)
+
+
+def _assert_same_bits(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+def _padded_book(U, B, K, R, vector_pi, seed):
+    """A random padded book with padded slots, fully masked users and
+    duplicate pools inside a bundle."""
+    rng = np.random.default_rng(seed)
+    idx = np.sort(rng.integers(0, R, (U, B, K)), axis=-1).astype(np.int32)
+    val = rng.uniform(-2, 4, (U, B, K)).astype(np.float32)
+    pad = rng.random((U, B, K)) < 0.3  # padded slots: idx 0, val 0
+    idx[pad], val[pad] = 0, 0.0
+    if K > 1:  # duplicate pools inside a bundle
+        dup = rng.random((U, B)) < 0.2
+        idx[..., 1] = np.where(dup, idx[..., 0], idx[..., 1])
+    mask = rng.random((U, B)) < 0.75
+    mask[rng.random(U) < 0.15] = False  # fully masked users
+    idx[~mask], val[~mask] = 0, 0.0
+    shape = (U, B) if vector_pi else (U,)
+    pi = rng.uniform(-5, 15, shape).astype(np.float32)
+    return SparseAuctionProblem(
+        idx=jnp.asarray(idx),
+        val=jnp.asarray(val),
+        bundle_mask=jnp.asarray(mask),
+        pi=jnp.asarray(pi),
+        base_cost=jnp.ones(R, jnp.float32),
+        supply_scale=jnp.ones(R, jnp.float32),
+        num_resources=R,
+    )
+
+
+def _fixed_k_csr(prob):
+    """The padded book as the always-on book stores it: every bundle owns
+    exactly K entries of the flat streams, padding included."""
+    U, B, K = prob.idx.shape
+    return csr_problem_from_arrays(
+        np.asarray(prob.idx).reshape(-1),
+        np.asarray(prob.val).reshape(-1),
+        np.arange(U * B + 1, dtype=np.int32) * K,
+        np.asarray(prob.bundle_mask),
+        np.asarray(prob.pi),
+        np.asarray(prob.base_cost),
+        np.asarray(prob.supply_scale),
+        k_bound=K,
+    )
+
+
+def _bundle_pick_outputs(prob, result, prices):
+    """Selection, both settles and the SYSTEM checks, each jitted through a
+    fresh wrapper so that it is traced anew with whatever
+    ``auction._take_bundle`` is at call time."""
+    r = prob.num_resources
+    args = (prob.idx, prob.val, prob.bundle_mask, prob.pi, prices)
+    sel = jax.jit(lambda *a: auction._sparse_selection(*a))(*args)
+    settles = [
+        jax.jit(
+            lambda i, v, p, c, a, exact=exact: auction._sparse_settle(
+                i, v, p, c, a, num_resources=r, exact=exact
+            )
+        )(prob.idx, prob.val, prices, sel[2], sel[3])
+        for exact in (False, True)
+    ]
+    checks = jax.jit(
+        lambda pr, res: auction._verify_system_checks.__wrapped__(pr, res, 1e-3)
+    )(prob, result)
+    return sel, settles, checks
+
+
+@pytest.mark.parametrize("B", [1, 6, 8])
+@pytest.mark.parametrize("vector_pi", [False, True], ids=["scalar_pi", "vector_pi"])
+def test_bundle_pick_is_bit_identical_to_take_along_axis(monkeypatch, B, vector_pi):
+    """Selection, settlement and verification pick bundles with selects; the
+    result is the gather's bit for bit (padded slots, fully masked users and
+    duplicate pools included)."""
+    prob = _padded_book(96, B, 3, 24, vector_pi, seed=B)
+    prices = _prices(24, seed=B)
+    result = clock_auction(prob, prices, ClockConfig(max_rounds=50))
+    # a second result whose winners report a neighbouring bundle, so the
+    # checks that read the winner's surplus see other values
+    rotated = dataclasses.replace(
+        result,
+        chosen_bundle=jnp.where(
+            result.won, (result.chosen_bundle + 1) % B, result.chosen_bundle
+        ),
+    )
+    got = [_bundle_pick_outputs(prob, res, prices) for res in (result, rotated)]
+    monkeypatch.setattr(auction, "_take_bundle", _gather_bundle)
+    want = [_bundle_pick_outputs(prob, res, prices) for res in (result, rotated)]
+    assert np.asarray(~prob.bundle_mask.any(axis=1)).any()  # masked users exist
+    for (g_sel, g_settles, g_checks), (w_sel, w_settles, w_checks) in zip(got, want):
+        _assert_same_bits(g_sel, w_sel)
+        for g, w in zip(g_settles, w_settles):
+            _assert_same_bits(g, w)
+        assert {k: bool(v) for k, v in g_checks.items()} == {
+            k: bool(v) for k, v in w_checks.items()
+        }
+
+
+@pytest.mark.parametrize("B", [1, 6, 8])
+@pytest.mark.parametrize("vector_pi", [False, True], ids=["scalar_pi", "vector_pi"])
+def test_fixed_k_csr_settles_as_its_padded_book(B, vector_pi):
+    """A CSR book whose bundles all hold K entries (the always-on book's
+    layout, whose padded views are a reshape rather than a gather) gives the
+    padded book's views, settlement and checks bit for bit."""
+    prob = _padded_book(96, B, 3, 24, vector_pi, seed=20 + B)
+    csr = _fixed_k_csr(prob)
+    assert csr.nnz == prob.idx.size
+    _assert_same_bits(
+        jax.jit(csr_padded_views)(csr), (prob.idx, prob.val)
+    )
+    start = _prices(24, seed=B)
+    fn = blocked_demand_fn(8)
+    got = clock_auction(csr, start, ClockConfig(max_rounds=50), demand_fn=fn)
+    want = clock_auction(prob, start, ClockConfig(max_rounds=50), demand_fn=fn)
+    _assert_same_bits(jax.tree.leaves(got), jax.tree.leaves(want))
+    assert verify_system(csr, got) == verify_system(prob, want)
+
+
+@pytest.mark.parametrize("shape", [(40, 8), (40, 8, 3), (40, 1, 5)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32, jnp.bool_])
+def test_take_bundle_matches_gather_bits(shape, dtype):
+    """Signed zeros, infinities and NaNs pass through the selects unchanged."""
+    rng = np.random.default_rng(len(shape))
+    x = rng.normal(size=shape).astype(np.float32)
+    if dtype == jnp.float32:
+        flat = x.reshape(-1)
+        flat[::7], flat[1::7], flat[2::7], flat[3::7] = -0.0, np.inf, -np.inf, np.nan
+    x = jnp.asarray(x > 0 if dtype == jnp.bool_ else x * 100).astype(dtype)
+    b = jnp.asarray(rng.integers(0, shape[1], shape[0]), jnp.int32)
+    got = jax.jit(auction._take_bundle)(x, b)
+    want = jax.jit(_gather_bundle)(x, b)
+    _assert_same_bits([got], [want])
+
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in its equations
+    (jit, while cond and body, cond branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _jaxpr_eqns(sub)
+
+
+@pytest.mark.parametrize("encoding", ["padded", "csr"])
+@pytest.mark.parametrize("vector_pi", [False, True], ids=["scalar_pi", "vector_pi"])
+def test_served_programs_gather_only_prices(vector_pi, encoding):
+    """The served clock (blocked fold) and the SYSTEM checks keep one gather:
+    ``prices[idx]``, a 1-D operand of length R.  No gather runs over the
+    bundle axis or over the book's flat streams, in the clock's while body
+    or anywhere else.  ``csr`` is the always-on book's encoding, the one
+    ``MarketService`` settles."""
+    R = 24
+    prob = _padded_book(64, 8, 3, R, vector_pi, seed=11)
+    if encoding == "csr":
+        prob = _fixed_k_csr(prob)
+    start = _prices(R, seed=3)
+    fn = blocked_demand_fn(8)
+    clock = jax.make_jaxpr(
+        lambda p, s: clock_auction(p, s, ClockConfig(), demand_fn=fn)
+    )(prob, start)
+    result = clock_auction(prob, start, ClockConfig(max_rounds=20), demand_fn=fn)
+    verify = jax.make_jaxpr(auction._verify_system_checks, static_argnums=(2,))(
+        prob, result, 1e-3
+    )
+    for closed in (clock, verify):
+        eqns = list(_jaxpr_eqns(closed.jaxpr))
+        assert any(e.primitive.name == "while" for e in eqns) == (closed is clock)
+        operands = [e.invars[0].aval.shape for e in eqns if e.primitive.name == "gather"]
+        assert operands and all(s == (R,) for s in operands), operands

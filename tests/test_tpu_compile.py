@@ -17,8 +17,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.auction import ClockConfig, blocked_demand_fn, clock_auction
-from repro.core.types import CSRAuctionProblem
+from repro.core.auction import (
+    ClockConfig,
+    _verify_system_checks,
+    blocked_demand_fn,
+    clock_auction,
+)
+from repro.core.types import CSRAuctionProblem, SparseAuctionResult
 from repro.kernels import ops
 from repro.kernels.clock_bid_eval import bid_eval
 from repro.kernels.sparse_bid_eval import sparse_bid_eval
@@ -145,3 +150,48 @@ def test_settle_program_compiles(one_chip, rows, b, k, r, nnz, cfg):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
     assert mem.generated_code_size_in_bytes < 32 * 2**20
+
+
+def _served_book(sharding, u=131072, b=8, k=3, r=24):
+    """Shape structs of the served book (``paper_service_100k``: 2¹⁷ slots,
+    8 XOR bundles of 3 pools, 24 pools, vector π) in the always-on book's
+    fixed-K CSR layout, and of its settled result."""
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    nnz = u * b * k
+    problem = CSRAuctionProblem(
+        idx=s((nnz,), jnp.int32), val=s((nnz,), jnp.float32),
+        rows=s((nnz,), jnp.int32), offsets=s((u * b + 1,), jnp.int32),
+        bundle_mask=s((u, b), jnp.bool_), pi=s((u, b), jnp.float32),
+        base_cost=s((r,), jnp.float32), supply_scale=s((r,), jnp.float32),
+        num_resources=r, k_bound=k,
+    )
+    result = SparseAuctionResult(
+        prices=s((r,), jnp.float32), alloc_idx=s((u, k), jnp.int32),
+        alloc_val=s((u, k), jnp.float32), chosen_bundle=s((u,), jnp.int32),
+        won=s((u,), jnp.bool_), payments=s((u,), jnp.float32),
+        excess_demand=s((r,), jnp.float32), rounds=s((), jnp.int32),
+        converged=s((), jnp.bool_),
+    )
+    return problem, result, s((r,), jnp.float32)
+
+
+@pytest.mark.parametrize("program", ["clock", "verify"])
+def test_served_programs_pick_bundles_without_gathers(one_chip, program):
+    """The served tick's clock and SYSTEM checks, on the cell's book as
+    ``MarketBook.device_problem`` hands it over, compile with no gather
+    fusion: the padded views are a reshape of the flat streams, the bundle
+    pick is selects, and ``prices[idx]`` becomes selects on the TPU."""
+    problem, result, start = _served_book(one_chip)
+    if program == "clock":
+        lowered = jax.jit(
+            lambda p, s: clock_auction(p, s, ClockConfig(), demand_fn=blocked_demand_fn(8))
+        ).lower(problem, start)
+    else:
+        lowered = _verify_system_checks.lower(problem, result, 1e-3)
+    text = lowered.compile().as_text()
+    gathers = [
+        line.strip()[:120]
+        for line in text.splitlines()
+        if "kind=kCustom" in line and "gather" in line
+    ]
+    assert not gathers, gathers
